@@ -39,7 +39,7 @@ from repro.core.config import (DEFAULT_SOURCE_CHUNK, ENGINE_BACKENDS,
                                FitConfig, require_array_weights,
                                resolve_backend, resolve_estep_backend,
                                resolve_source_chunk)
-from repro.core.gmm import GMM
+from repro.core.gmm import GMM, MATMUL_PRECISION
 from repro.data.sources import DataSource, prefetch_blocks
 
 
@@ -269,11 +269,12 @@ def _e_step_stats_reference(gmm: GMM, x: jax.Array,
     log_norm = jax.scipy.special.logsumexp(lp, axis=1)               # (N,)
     resp = jnp.exp(lp - log_norm[:, None]) * w[:, None]              # (N, K)
     s0 = jnp.sum(resp, axis=0)                                       # (K,)
-    s1 = resp.T @ x                                                  # (K, d)
+    s1 = jnp.matmul(resp.T, x, precision=MATMUL_PRECISION)          # (K, d)
     if gmm.is_diagonal:
-        s2 = resp.T @ (x * x)                                        # (K, d)
+        s2 = jnp.matmul(resp.T, x * x, precision=MATMUL_PRECISION)  # (K, d)
     else:
-        s2 = jnp.einsum("nk,ni,nj->kij", resp, x, x)                 # (K, d, d)
+        s2 = jnp.einsum("nk,ni,nj->kij", resp, x, x,
+                        precision=MATMUL_PRECISION)                  # (K, d, d)
     loglik = jnp.sum(log_norm * w)
     return SufficientStats(s0, s1, s2, loglik, jnp.sum(w))
 
@@ -447,9 +448,10 @@ def log_prob_chunked(gmm: GMM, x: jax.Array,
     :class:`DataSource` (the per-row *output* is still O(N), but only 4
     bytes a row — the (N, K) block never exists).
 
-    Every path runs the ONE jitted block (``_log_prob_block_jit``), which
-    is row-wise bit-stable across batch shapes — so chunked, full-batch
-    and the serving engine's padded-slab scores are bit-identical.
+    Every path runs the ONE jitted block (``_log_prob_block_jit``), so
+    chunked, full-batch and the serving engine's padded-slab scores agree
+    to a few float32 ulps; XLA does not promise one accumulation order
+    across batch shapes, so they need not share every bit.
     """
     backend = resolve_backend(backend, fused_supported=gmm.is_diagonal)
     if isinstance(x, DataSource):
@@ -531,11 +533,12 @@ def label_stats(x: jax.Array, assignments: jax.Array, k: int,
         cols = jnp.arange(k, dtype=ab.dtype)[None, :]
         oh = (ab[:, None] == cols).astype(xb.dtype) * wb[:, None]
         s0 = jnp.sum(oh, axis=0)
-        s1 = oh.T @ xb
+        s1 = jnp.matmul(oh.T, xb, precision=MATMUL_PRECISION)
         if covariance_type == "diag":
-            s2 = oh.T @ (xb * xb)
+            s2 = jnp.matmul(oh.T, xb * xb, precision=MATMUL_PRECISION)
         else:
-            s2 = jnp.einsum("nk,ni,nj->kij", oh, xb, xb)
+            s2 = jnp.einsum("nk,ni,nj->kij", oh, xb, xb,
+                            precision=MATMUL_PRECISION)
         return SufficientStats(s0, s1, s2, jnp.zeros((), xb.dtype),
                                jnp.sum(wb))
 

@@ -30,6 +30,7 @@ from repro.core.config import (FitConfig, is_source_list,
                                resolve_source_chunk)
 from repro.core.em import (SufficientStats, reduce_rows,
                            streaming_map_reduce, streaming_reduce)
+from repro.core.gmm import MATMUL_PRECISION
 from repro.data.sources import DataSource, prefetch_blocks
 
 # Rows the k-means++ seeding pass works from when the dataset is larger:
@@ -65,7 +66,8 @@ def _sq_dists(x: jax.Array, centers: jax.Array) -> jax.Array:
     """Squared euclidean distances (N, K) via the matmul identity."""
     x2 = jnp.sum(x * x, axis=1, keepdims=True)           # (N, 1)
     c2 = jnp.sum(centers * centers, axis=1)[None, :]     # (1, K)
-    return jnp.maximum(x2 - 2.0 * (x @ centers.T) + c2, 0.0)
+    xc = jnp.matmul(x, centers.T, precision=MATMUL_PRECISION)
+    return jnp.maximum(x2 - 2.0 * xc + c2, 0.0)
 
 
 def _assign_block(xb: jax.Array, centers: jax.Array,
@@ -99,7 +101,9 @@ def _sweep_block(xb: jax.Array, wb: jax.Array, centers: jax.Array,
     k = centers.shape[0]
     idx, d2 = _assign_block(xb, centers, backend)
     oh = _labels_onehot(idx, k, wb, xb.dtype)
-    return jnp.sum(oh, axis=0), oh.T @ xb, jnp.sum(d2 * wb)
+    return (jnp.sum(oh, axis=0),
+            jnp.matmul(oh.T, xb, precision=MATMUL_PRECISION),
+            jnp.sum(d2 * wb))
 
 
 def kmeans_plusplus(key: jax.Array, x: jax.Array, k: int,
@@ -176,7 +180,8 @@ def kmeans(key: jax.Array, x: jax.Array, k: int,
     def block_stats(xb, wb, centers):
         idx, d2 = _assign_block(xb, centers, backend)
         oh = _labels_onehot(idx, k, wb, xb.dtype)
-        return (jnp.sum(oh, axis=0), oh.T @ xb, jnp.sum(d2 * wb)), idx
+        sums = jnp.matmul(oh.T, xb, precision=MATMUL_PRECISION)
+        return (jnp.sum(oh, axis=0), sums, jnp.sum(d2 * wb)), idx
 
     def sweep(centers):
         """One assignment pass -> ((counts, sums, inertia), assignments)."""
@@ -193,11 +198,12 @@ def kmeans(key: jax.Array, x: jax.Array, k: int,
         if backend == "fused":
             idx, _ = _assign_block(xb, centers, backend)
         else:
-            score = xb @ centers.T - 0.5 * jnp.sum(
-                centers * centers, axis=1)[None, :]
+            xc = jnp.matmul(xb, centers.T, precision=MATMUL_PRECISION)
+            score = xc - 0.5 * jnp.sum(centers * centers, axis=1)[None, :]
             idx = jnp.argmax(score, axis=1).astype(jnp.int32)
         oh = _labels_onehot(idx, k, wb, xb.dtype)
-        return jnp.sum(oh, axis=0), oh.T @ xb
+        return (jnp.sum(oh, axis=0),
+                jnp.matmul(oh.T, xb, precision=MATMUL_PRECISION))
 
     def sweep_stats(centers):
         """Reduce-only sweep for the Lloyd loop (assignments not collected)."""
@@ -475,11 +481,12 @@ def kmeans_label_block(centers: jax.Array, xb: jax.Array, wb: jax.Array,
     idx, _ = _assign_block(xb, centers, backend)
     oh = _labels_onehot(idx, k, wb, xb.dtype)
     s0 = jnp.sum(oh, axis=0)
-    s1 = oh.T @ xb
+    s1 = jnp.matmul(oh.T, xb, precision=MATMUL_PRECISION)
     if covariance_type == "diag":
-        s2 = oh.T @ (xb * xb)
+        s2 = jnp.matmul(oh.T, xb * xb, precision=MATMUL_PRECISION)
     else:
-        s2 = jnp.einsum("nk,ni,nj->kij", oh, xb, xb)
+        s2 = jnp.einsum("nk,ni,nj->kij", oh, xb, xb,
+                        precision=MATMUL_PRECISION)
     return SufficientStats(s0, s1, s2, jnp.zeros((), xb.dtype),
                            jnp.sum(wb))
 

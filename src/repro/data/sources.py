@@ -397,7 +397,8 @@ def _synth_block(cum_weights, means, scale, key, start, size):
     if scale.ndim == 2:                                     # diagonal: std
         rows = mu + scale[comp] * eps
     else:
-        rows = mu + jnp.einsum("nij,nj->ni", scale[comp], eps)  # Cholesky
+        rows = mu + jnp.einsum("nij,nj->ni", scale[comp], eps,  # Cholesky
+                               precision=jax.lax.Precision.HIGHEST)
     return jax.lax.dynamic_slice_in_dim(rows, start - tile0 * _TILE, size)
 
 

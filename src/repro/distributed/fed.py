@@ -6,9 +6,10 @@ collective patterns:
   FedGenGMM (one-shot):  local EM runs with ZERO cross-shard communication,
       then the single communication round of the paper is literally ONE
       jax.lax.all_gather of the (K, 2d+1) parameter blocks + dataset sizes.
-      The server-side merge/sample/refit then runs replicated (every shard
-      computes the same global model, as a real parameter server would
-      broadcast it anyway).
+      The server-side merge/sample/refit then runs on the mesh's first
+      device, as one parameter server would (outside shard_map, a
+      mesh-replicated refit would ask the TPU compiler to partition its
+      Pallas kernels, which it refuses).
 
   DEM / FedEM / FedKMeans (iterative): every round psums the per-client
       payload (EM sufficient statistics, or k-means label statistics)
@@ -32,7 +33,6 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.config import FitConfig, resolve_backend
 from repro.core.dem import DEMStrategy, _resolve_init
@@ -62,14 +62,14 @@ def fedgen_sharded(mesh, key, data, mask, k: int, k_global: int,
     """One-shot FedGenGMM over a device mesh.
 
     data: (C, N, d), mask: (C, N) with C divisible by the data-axis size.
-    Returns ShardedFedResult (global model replicated).
+    Returns ShardedFedResult, on the mesh's first device (the server).
     ``config`` (a :class:`FitConfig`) selects the E-step engine for both
-    the per-shard local fits and the replicated server refit; the loose
+    the per-shard local fits and the server refit; the loose
     ``max_iter``/``tol``/``estep_backend``/``chunk_size`` knobs are the
     legacy spelling and are folded into one config (ignored when
     ``config`` is given).
 
-    ``synthetic="source"`` makes the replicated server refit out-of-core:
+    ``synthetic="source"`` makes the server refit out-of-core:
     the synthetic replay set |S| = H·K·C — the one dataset in this runtime
     that *grows with the client count* — is consumed as a seeded
     :class:`SyntheticGMMSource` block stream instead of being materialized
@@ -87,11 +87,8 @@ def fedgen_sharded(mesh, key, data, mask, k: int, k_global: int,
     c = data.shape[0]
     assert c % n_shards == 0, (c, n_shards)
 
-    def local_part(key, data_shard, mask_shard):
+    def local_part(keys, data_shard, mask_shard):
         """Runs per shard: train this shard's clients, no communication."""
-        nc = data_shard.shape[0]
-        keys = jax.random.split(key[0], nc)
-
         def one(kk, x, w):
             res = fit_gmm_cfg(kk, x, k, cfg, sample_weight=w)
             return res.gmm.weights, res.gmm.means, res.gmm.covs
@@ -105,17 +102,26 @@ def fedgen_sharded(mesh, key, data, mask, k: int, k_global: int,
         sz_all = jax.lax.all_gather(sizes, axis, tiled=True)
         return w_all, mu_all, cov_all, sz_all
 
-    keys = jax.random.split(key, n_shards)
+    # The same key schedule as the single-process FedGenStrategy (one key
+    # per client, then sample/refit keys), so a mesh run reproduces the
+    # unsharded pipeline for the same key.
+    k_local, k_agg = jax.random.split(key)
     spec = P(axis)
-    fn = shard_map(local_part, mesh=mesh,
-                   in_specs=(P(axis), spec, spec),
-                   out_specs=(P(), P(), P(), P()), check_rep=False)
-    w_all, mu_all, cov_all, sz_all = fn(keys, data, mask)
+    fn = jax.shard_map(local_part, mesh=mesh,
+                       in_specs=(spec, spec, spec),
+                       out_specs=(P(), P(), P(), P()), check_vma=False)
+    w_all, mu_all, cov_all, sz_all = fn(jax.random.split(k_local, c), data,
+                                        mask)
 
-    # server side (replicated): merge -> sample -> refit
+    # server side, on one device: merge -> sample -> refit. The gathered
+    # blocks go through the host, which drops their mesh type (a direct
+    # device_put keeps the explicit mesh axes in later avals).
+    w_all, mu_all, cov_all, sz_all = jax.device_put(
+        jax.device_get((w_all, mu_all, cov_all, sz_all)),
+        mesh.devices.flat[0])
     merged = merge_gmms_stacked(w_all, mu_all, cov_all, sz_all)
     n_synth = h * k * c
-    k_sample, k_fit = jax.random.split(jax.random.fold_in(key, 1))
+    k_sample, k_fit = jax.random.split(k_agg)
     if synthetic == "source":
         synth = SyntheticGMMSource(merged, n_synth, k_sample)
     else:

@@ -45,7 +45,6 @@ from typing import Any, Optional, Protocol, Sequence, runtime_checkable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.data.sources import DataSource
@@ -357,10 +356,10 @@ class ShardedClients:
                 return jax.tree.map(lambda s: jax.lax.psum(s, axis), local)
 
             w = jnp.ones((c,)) if weights is None else weights
-            fn = shard_map(shard_fn, mesh=self.mesh,
-                           in_specs=(P(), P(axis), P(axis), P(axis),
-                                     P(axis), P(), P()),
-                           out_specs=P(), check_rep=False)
+            fn = jax.shard_map(shard_fn, mesh=self.mesh,
+                               in_specs=(P(), P(axis), P(axis), P(axis),
+                                         P(axis), P(), P()),
+                               out_specs=P(), check_vma=False)
             return fn(state, jnp.arange(c), w, self.data, self.mask,
                       tk, tp)
 
@@ -389,10 +388,10 @@ class ShardedClients:
             return jax.tree.map(lambda s: jax.lax.psum(s, axis), total)
 
         w = jnp.ones((m,)) if weights is None else weights
-        fn = shard_map(shard_fn, mesh=self.mesh,
-                       in_specs=(P(), P(axis), P(), P(), P(axis), P(axis),
-                                 P(), P()),
-                       out_specs=P(), check_rep=False)
+        fn = jax.shard_map(shard_fn, mesh=self.mesh,
+                           in_specs=(P(), P(axis), P(), P(), P(axis),
+                                     P(axis), P(), P()),
+                           out_specs=P(), check_vma=False)
         return fn(state, jnp.arange(c), cohort, w, self.data, self.mask,
                   tk, tp)
 

@@ -31,13 +31,15 @@ def _estep_kernel(x_ref, w_ref, a_ref, b_ref, c_ref,
         s2_ref[...] = jnp.zeros_like(s2_ref)
         ll_ref[...] = jnp.zeros_like(ll_ref)
 
+    # full float32 dots: a bfloat16 pass would move the log densities by
+    # whole nats, since the identity's large terms cancel (repro.core.gmm)
+    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST)
     x = x_ref[...].astype(jnp.float32)            # (bn, d)
     w = w_ref[...].astype(jnp.float32)            # (bn, 1)
     xx = x * x
-    lp = jnp.dot(xx, a_ref[...].astype(jnp.float32),
-                 preferred_element_type=jnp.float32)
-    lp += jnp.dot(x, b_ref[...].astype(jnp.float32),
-                  preferred_element_type=jnp.float32)
+    lp = dot(xx, a_ref[...].astype(jnp.float32))
+    lp += dot(x, b_ref[...].astype(jnp.float32))
     lp += c_ref[...].astype(jnp.float32)          # (bn, K)
     m = jnp.max(lp, axis=1, keepdims=True)        # (bn, 1)
     p = jnp.exp(lp - m)
@@ -45,8 +47,8 @@ def _estep_kernel(x_ref, w_ref, a_ref, b_ref, c_ref,
     log_norm = m + jnp.log(denom)                 # (bn, 1)
     resp = (p / denom) * w                        # (bn, K)
     s0_ref[...] += jnp.sum(resp, axis=0, keepdims=True)            # (1, K)
-    s1_ref[...] += jnp.dot(resp.T, x, preferred_element_type=jnp.float32)
-    s2_ref[...] += jnp.dot(resp.T, xx, preferred_element_type=jnp.float32)
+    s1_ref[...] += dot(resp.T, x)
+    s2_ref[...] += dot(resp.T, xx)
     ll_ref[...] += jnp.sum(log_norm * w, keepdims=True)
 
 

@@ -32,8 +32,12 @@ def _logpdf_kernel(x_ref, a_ref, b_ref, c_ref, out_ref):
     x = x_ref[...].astype(jnp.float32)           # (bn, d)
     a = a_ref[...].astype(jnp.float32)           # (d, bk)
     b = b_ref[...].astype(jnp.float32)           # (d, bk)
-    acc = jnp.dot(x * x, a, preferred_element_type=jnp.float32)
-    acc += jnp.dot(x, b, preferred_element_type=jnp.float32)
+    # full float32 dots: a bfloat16 pass would move the log densities by
+    # whole nats, since the identity's large terms cancel (repro.core.gmm)
+    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST)
+    acc = dot(x * x, a)
+    acc += dot(x, b)
     out_ref[...] = (acc + c_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
 
 

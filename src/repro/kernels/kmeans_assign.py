@@ -20,7 +20,9 @@ def _assign_kernel(x_ref, ct_ref, c2_ref, idx_ref, dist_ref):
     ct = ct_ref[...].astype(jnp.float32)          # (d, K)
     c2 = c2_ref[...].astype(jnp.float32)          # (1, K) (+inf on padding)
     x2 = jnp.sum(x * x, axis=1, keepdims=True)    # (bn, 1)
-    d2 = x2 - 2.0 * jnp.dot(x, ct, preferred_element_type=jnp.float32) + c2
+    xc = jnp.dot(x, ct, preferred_element_type=jnp.float32,
+                 precision=jax.lax.Precision.HIGHEST)  # full f32, as in gmm
+    d2 = x2 - 2.0 * xc + c2
     d2 = jnp.maximum(d2, 0.0)
     idx_ref[...] = jnp.argmin(d2, axis=1, keepdims=True).astype(jnp.int32)
     dist_ref[...] = jnp.min(d2, axis=1, keepdims=True)

@@ -1,10 +1,14 @@
-"""Public jit'd wrappers around the Pallas kernels.
+"""Public wrappers around the Pallas kernels.
 
-Handle padding to TPU tile boundaries (lanes = 128, tunable N/K blocks),
-parameter re-packing into the matmul-identity form, and automatic fallback
-to ``interpret=True`` when not running on TPU (this container is CPU-only;
-interpret mode executes the kernel body in Python and is bit-compatible
-with the TPU lowering at f32).
+Handle padding to TPU tile boundaries (lanes = 128, tunable N/K blocks)
+and parameter re-packing into the matmul-identity form. With
+``interpret=None`` a kernel compiles for the TPU (Mosaic) when JAX's
+default backend is a TPU, and runs in Pallas interpret mode anywhere else:
+the kernel body evaluated by XLA on the host, slow, and there for parity
+tests on the CPU. Interpret mode computes the same float32 math but does
+not promise the TPU's bits. On the chip, ``chip_smoke.py`` checks that the
+compiled programs hold the Mosaic kernel (``tpu_custom_call``), so a fall
+into interpret mode or the reference path fails there.
 """
 from __future__ import annotations
 

@@ -5,6 +5,7 @@ import jax
 import jax.numpy as jnp
 
 LOG_2PI = 1.8378770664093453
+HIGHEST = jax.lax.Precision.HIGHEST  # float32 dots on every backend
 
 
 def gmm_logpdf_ref(x: jax.Array, means: jax.Array, variances: jax.Array,
@@ -15,8 +16,8 @@ def gmm_logpdf_ref(x: jax.Array, means: jax.Array, variances: jax.Array,
     """
     d = x.shape[-1]
     inv_var = 1.0 / variances
-    a = (x * x) @ inv_var.T
-    b = x @ (means * inv_var).T
+    a = jnp.matmul(x * x, inv_var.T, precision=HIGHEST)
+    b = jnp.matmul(x, (means * inv_var).T, precision=HIGHEST)
     c = jnp.sum(means * means * inv_var + jnp.log(variances), axis=-1)
     out = -0.5 * (a - 2.0 * b + c[None, :] + d * LOG_2PI)
     if log_weights is not None:
@@ -37,8 +38,8 @@ def estep_stats_ref(x: jax.Array, means: jax.Array, variances: jax.Array,
     log_norm = jax.scipy.special.logsumexp(lp, axis=1)           # (N,)
     resp = jnp.exp(lp - log_norm[:, None]) * w[:, None]          # (N, K)
     s0 = jnp.sum(resp, axis=0)
-    s1 = resp.T @ x
-    s2 = resp.T @ (x * x)
+    s1 = jnp.matmul(resp.T, x, precision=HIGHEST)
+    s2 = jnp.matmul(resp.T, x * x, precision=HIGHEST)
     loglik = jnp.sum(log_norm * w)
     return s0, s1, s2, loglik
 
@@ -47,5 +48,6 @@ def kmeans_assign_ref(x: jax.Array, centers: jax.Array):
     """Squared distances + argmin assignment. (N,d),(K,d) -> ((N,), (N,))."""
     x2 = jnp.sum(x * x, axis=1, keepdims=True)
     c2 = jnp.sum(centers * centers, axis=1)[None, :]
-    d2 = jnp.maximum(x2 - 2.0 * (x @ centers.T) + c2, 0.0)
+    xc = jnp.matmul(x, centers.T, precision=HIGHEST)
+    d2 = jnp.maximum(x2 - 2.0 * xc + c2, 0.0)
     return jnp.argmin(d2, axis=1), jnp.min(d2, axis=1)

@@ -60,17 +60,17 @@ def _score_slab(gmm: GMM, slab: jax.Array, mask: jax.Array, *,
 
     Per-row math is exactly the training engine's
     (``repro.core.em._log_prob_block`` — kernel-dispatched, so "fused"
-    rides the Pallas ``gmm_logpdf`` on TPU), which is what pins engine
-    scores bit-identical to ``repro.api.log_prob``: a row's mixture
-    density never depends on its batch peers, and masked padding rows
-    are multiplied to zero AFTER the per-row computation (``x * 1.0`` is
-    exact in IEEE f32, so valid rows are untouched). ``slab`` and
-    ``mask`` are donated — both are dead after the call (the engine
-    rebuilds them host-side every micro-batch), and XLA aliases whatever
-    shapes line up (the ``(S, R)`` mask buffer becomes the ``(S, R)``
-    score buffer in log_prob/anomaly mode; the rest is simply freed
-    early). The engine suppresses XLA's "donated buffer not usable"
-    note for the shapes that can't alias."""
+    rides the Pallas ``gmm_logpdf`` on TPU), so engine scores agree with
+    ``repro.api.log_prob`` to a few float32 ulps: the same math, but XLA
+    may accumulate a dot product in another order at another batch
+    shape. Masked padding rows are multiplied to zero AFTER the per-row
+    computation (``x * 1.0`` is exact in IEEE f32, so valid rows are
+    untouched). ``slab`` and ``mask`` are donated — both are dead after
+    the call (the engine rebuilds them host-side every micro-batch), and
+    XLA aliases whatever shapes line up (the ``(S, R)`` mask buffer
+    becomes the ``(S, R)`` score buffer in log_prob/anomaly mode; the
+    rest is simply freed early). The engine suppresses XLA's "donated
+    buffer not usable" note for the shapes that can't alias."""
     s, r, d = slab.shape
     x = slab.reshape(s * r, d)
     if mode == "responsibilities":
@@ -142,6 +142,13 @@ class ScoringEngine:
     def dim(self) -> int:
         """Feature dimension every request's rows must match."""
         return self._pool.dim
+
+    @property
+    def backend(self) -> str:
+        """The scoring implementation the config's backend resolved to
+        for the installed model: ``"fused"`` (Pallas ``gmm_logpdf``) or
+        ``"reference"`` (pure jnp)."""
+        return self._backend
 
     @property
     def swap_pending(self) -> bool:

@@ -72,7 +72,7 @@ def sharded_results():
     proc = subprocess.run([sys.executable, "-c", SCRIPT],
                           capture_output=True, text=True, timeout=900,
                           env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                               "HOME": "/root"})
+                               "HOME": "/root", "JAX_PLATFORMS": "cpu"})
     assert proc.returncode == 0, proc.stderr[-3000:]
     return json.loads(proc.stdout.strip().splitlines()[-1])
 

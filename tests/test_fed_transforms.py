@@ -11,6 +11,7 @@ mirroring tests/test_distributed.py).
 """
 import dataclasses
 import json
+import os
 import subprocess
 import sys
 import textwrap
@@ -161,8 +162,11 @@ class TestBitIdentity:
             }
             print(json.dumps(out))
         """)
+        # the child must stay off the accelerator: the parent process
+        # already holds it
         res = subprocess.run([sys.executable, "-c", script],
-                             capture_output=True, text=True, check=True)
+                             capture_output=True, text=True, check=True,
+                             env={**os.environ, "JAX_PLATFORMS": "cpu"})
         out = json.loads(res.stdout.strip().splitlines()[-1])
         assert out["identity_same"], "sharded Identity run drifted"
         assert out["mask_same"], "sharded PairwiseMask run drifted"
