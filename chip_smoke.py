@@ -17,8 +17,7 @@ generated from ``--seed``. In one process, in order:
    against a float64 numpy reference that shares no code with ``repro``;
 6. proof that the Pallas kernels ran: ``"auto"`` resolved to
    ``"fused"``, and the compiled E-step and scoring programs hold a
-   ``tpu_custom_call``;
-7. per-phase wall and compile times (smoke timings, not benchmarks).
+   ``tpu_custom_call``.
 
 With ``--chips 4`` it runs only the sharded path instead:
 ``dem_sharded`` and ``fedgen_sharded`` on a 4-device mesh (5 clients per
@@ -35,7 +34,6 @@ from __future__ import annotations
 import argparse
 import json
 import sys
-import time
 from pathlib import Path
 
 import numpy as np
@@ -60,50 +58,6 @@ def check(ok: bool, what: str) -> None:
     if not ok:
         raise SmokeFailure(what)
     print(f"  ok: {what}", flush=True)
-
-
-# ----------------------------------------------------------------------
-# Timing: wall time per phase, compile time from JAX's own events
-# ----------------------------------------------------------------------
-
-_COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
-                   "/jax/core/compile/jaxpr_to_mlir_module_duration",
-                   "/jax/core/compile/backend_compile_duration")
-
-
-class PhaseTimer:
-    """Wall seconds per phase, and the seconds JAX reports spending on
-    tracing, lowering and compiling (or loading a cached executable)
-    within it."""
-
-    def __init__(self):
-        import jax
-        self.rows: list[tuple[str, float, float]] = []
-        self._compile = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event, duration, **_):
-        if event in _COMPILE_EVENTS:
-            self._compile += duration
-
-    def phase(self, name):
-        timer = self
-
-        class _Phase:
-            def __enter__(self):
-                self.t0, self.c0 = time.perf_counter(), timer._compile
-
-            def __exit__(self, *exc):
-                wall = time.perf_counter() - self.t0
-                timer.rows.append((name, wall, timer._compile - self.c0))
-                print(f"[{name}] {wall:.3f} s wall", flush=True)
-
-        return _Phase()
-
-    def report(self):
-        print("smoke timings (one cold-or-cached run; not benchmark numbers):")
-        for name, wall, comp in self.rows:
-            print(f"  {name:<24} wall {wall:9.3f} s   compile {comp:9.3f} s")
 
 
 # ----------------------------------------------------------------------
@@ -224,7 +178,7 @@ def held_out_ll(gmm, x) -> float:
 # One chip: the main path
 # ----------------------------------------------------------------------
 
-def run_main(args, timer: PhaseTimer) -> None:
+def run_main(args) -> None:
     import jax
     import jax.numpy as jnp
     from repro.api import DEM, FedGenGMM, FitConfig, GMMEstimator
@@ -233,11 +187,10 @@ def run_main(args, timer: PhaseTimer) -> None:
     from repro.serve import ScoreConfig, ScoreRequest, ScoringEngine
     from repro.serve.engine import _score_slab
 
-    with timer.phase("data"):
-        ds, split = make_wadi(args.rows, args.seed)
-        k = ds.k_global
-        x_test = ds.x_test_in.astype(np.float32)
-        x_ood = ds.x_test_ood.astype(np.float32)
+    ds, split = make_wadi(args.rows, args.seed)
+    k = ds.k_global
+    x_test = ds.x_test_in.astype(np.float32)
+    x_ood = ds.x_test_ood.astype(np.float32)
     print(f"data: {ds.x_train.shape[0]} train rows, d={ds.x_train.shape[1]}, "
           f"K={k}, {split.data.shape[0]} clients, slab {split.data.shape}, "
           f"{x_test.shape[0]} held-out rows, {x_ood.shape[0]} attack rows",
@@ -247,18 +200,15 @@ def run_main(args, timer: PhaseTimer) -> None:
     check(cfg.resolved_estep() == "fused",
           "FitConfig backend 'auto' resolves to the fused E-step kernel")
 
-    with timer.phase("fedgen"):
-        fed = FedGenGMM(k_clients=k, k_global=k, config=cfg)
-        fed_res = fed.run(split)
-        jax.block_until_ready(fed_res.global_gmm)
-    with timer.phase("centralized"):
-        central = GMMEstimator(k, config=cfg).fit(
-            ds.x_train.astype(np.float32))
-        jax.block_until_ready(central.gmm_)
-    with timer.phase("dem"):
-        dem = DEM(k, config=cfg.replace(max_iter=DEM_ROUNDS))
-        dem_res = dem.run(split)
-        jax.block_until_ready(dem_res.global_gmm)
+    fed = FedGenGMM(k_clients=k, k_global=k, config=cfg)
+    fed_res = fed.run(split)
+    jax.block_until_ready(fed_res.global_gmm)
+    central = GMMEstimator(k, config=cfg).fit(
+        ds.x_train.astype(np.float32))
+    jax.block_until_ready(central.gmm_)
+    dem = DEM(k, config=cfg.replace(max_iter=DEM_ROUNDS))
+    dem_res = dem.run(split)
+    jax.block_until_ready(dem_res.global_gmm)
     lls = {"fedgen": held_out_ll(fed.global_gmm_, x_test),
            "centralized": held_out_ll(central.gmm_, x_test),
            "dem": held_out_ll(dem.global_gmm_, x_test)}
@@ -281,15 +231,14 @@ def run_main(args, timer: PhaseTimer) -> None:
                       rng.integers(len(x_test), len(pool), n),
                       rng.integers(0, len(x_test), n)) for n in sizes]
     requests = [ScoreRequest(i, pool[p]) for i, p in enumerate(picks)]
-    with timer.phase("serve"):
-        engine = ScoringEngine(gmm, ScoreConfig(mode="anomaly", slots=8,
-                                                rows_per_slot=512))
-        for r in requests:
-            engine.submit(r)
-        results, steps = [], 0
-        while engine.pending_requests and steps < 10 * N_REQUESTS:
-            results.extend(engine.step())
-            steps += 1
+    engine = ScoringEngine(gmm, ScoreConfig(mode="anomaly", slots=8,
+                                            rows_per_slot=512))
+    for r in requests:
+        engine.submit(r)
+    results, steps = [], 0
+    while engine.pending_requests and steps < 10 * N_REQUESTS:
+        results.extend(engine.step())
+        steps += 1
     print(f"serving: {len(requests)} requests, {int(sizes.sum())} rows, "
           f"{steps} micro-batches", flush=True)
     got = {r.rid: r for r in results}
@@ -301,40 +250,39 @@ def run_main(args, timer: PhaseTimer) -> None:
           "the engine's 'auto' backend resolved to the fused kernel")
 
     # -- correctness against float64 numpy ------------------------------------
-    with timer.phase("reference check"):
-        served = np.concatenate([got[i].scores for i in range(len(requests))])
-        rows = np.concatenate([r.rows for r in requests])
-        ref = ref_logsumexp(ref_weighted_logpdf(rows, gmm))
-        tol = score_tolerance(rows, gmm, ref)
-        err = np.abs(-served.astype(np.float64) - ref)
-        print(f"served scores vs float64: max |err| {err.max():.3e}, "
-              f"max err/tol {np.max(err / tol):.3f}", flush=True)
-        check(bool(np.all(err <= tol)),
-              "served anomaly scores match the float64 log density")
+    served = np.concatenate([got[i].scores for i in range(len(requests))])
+    rows = np.concatenate([r.rows for r in requests])
+    ref = ref_logsumexp(ref_weighted_logpdf(rows, gmm))
+    tol = score_tolerance(rows, gmm, ref)
+    err = np.abs(-served.astype(np.float64) - ref)
+    print(f"served scores vs float64: max |err| {err.max():.3e}, "
+          f"max err/tol {np.max(err / tol):.3f}", flush=True)
+    check(bool(np.all(err <= tol)),
+          "served anomaly scores match the float64 log density")
 
-        ref_api = np.asarray(jax.jit(lambda g, x: g.log_prob(x))(
-            gmm, jnp.asarray(rows)), np.float64)
-        err_api = np.abs(ref_api - ref)
-        print(f"XLA reference log_prob vs float64: max |err| "
-              f"{err_api.max():.3e}, max err/tol "
-              f"{np.max(err_api / tol):.3f}", flush=True)
-        check(bool(np.all(err_api <= tol)),
-              "XLA reference-path log densities match the float64 density")
+    ref_api = np.asarray(jax.jit(lambda g, x: g.log_prob(x))(
+        gmm, jnp.asarray(rows)), np.float64)
+    err_api = np.abs(ref_api - ref)
+    print(f"XLA reference log_prob vs float64: max |err| "
+          f"{err_api.max():.3e}, max err/tol "
+          f"{np.max(err_api / tol):.3f}", flush=True)
+    check(bool(np.all(err_api <= tol)),
+          "XLA reference-path log densities match the float64 density")
 
-        xs = split.data[:, :CHECK_ROWS_PER_CLIENT]
-        ws = split.mask[:, :CHECK_ROWS_PER_CLIENT]
-        client_estep = jax.jit(jax.vmap(
-            lambda g, x, w: e_step_stats(g, x, w, "auto"),
-            in_axes=(None, 0, 0)))
-        stats = jax.device_get(client_estep(gmm, xs, ws))
-        ratios = [estep_error_ratio(xs[c], ws[c], gmm,
-                                    type(stats)(*(s[c] for s in stats)))
-                  for c in range(xs.shape[0])]
-        print(f"fused E-step, {xs.shape[0]} clients x {xs.shape[1]} rows: "
-              f"err/tol per client {np.round(ratios, 4).tolist()}",
-              flush=True)
-        check(max(ratios) <= 1.0,
-              "every client's fused E-step statistics match float64")
+    xs = split.data[:, :CHECK_ROWS_PER_CLIENT]
+    ws = split.mask[:, :CHECK_ROWS_PER_CLIENT]
+    client_estep = jax.jit(jax.vmap(
+        lambda g, x, w: e_step_stats(g, x, w, "auto"),
+        in_axes=(None, 0, 0)))
+    stats = jax.device_get(client_estep(gmm, xs, ws))
+    ratios = [estep_error_ratio(xs[c], ws[c], gmm,
+                                type(stats)(*(s[c] for s in stats)))
+              for c in range(xs.shape[0])]
+    print(f"fused E-step, {xs.shape[0]} clients x {xs.shape[1]} rows: "
+          f"err/tol per client {np.round(ratios, 4).tolist()}",
+          flush=True)
+    check(max(ratios) <= 1.0,
+          "every client's fused E-step statistics match float64")
 
     labels = np.concatenate([is_ood[p] for p in picks])
     print(f"anomaly AUC-PR of attack rows (served scores): "
@@ -342,19 +290,18 @@ def run_main(args, timer: PhaseTimer) -> None:
           f"{labels.mean():.3f})", flush=True)
 
     # -- the kernels really ran on the chip ---------------------------------
-    with timer.phase("kernel proof"):
-        estep_hlo = client_estep.lower(gmm, xs, ws).compile().as_text()
-        check("tpu_custom_call" in estep_hlo,
-              "the compiled vmapped E-step holds a Pallas tpu_custom_call")
-        geometry = (engine.config.slots, engine.config.rows_per_slot)
-        slab = jax.ShapeDtypeStruct(geometry + (gmm.n_features,),
-                                    jnp.float32)
-        mask = jax.ShapeDtypeStruct(geometry, jnp.float32)
-        score_hlo = _score_slab.lower(gmm, slab, mask, mode="anomaly",
-                                      backend=engine.backend
-                                      ).compile().as_text()
-        check("tpu_custom_call" in score_hlo,
-              "the compiled scoring step holds a Pallas tpu_custom_call")
+    estep_hlo = client_estep.lower(gmm, xs, ws).compile().as_text()
+    check("tpu_custom_call" in estep_hlo,
+          "the compiled vmapped E-step holds a Pallas tpu_custom_call")
+    geometry = (engine.config.slots, engine.config.rows_per_slot)
+    slab = jax.ShapeDtypeStruct(geometry + (gmm.n_features,),
+                                jnp.float32)
+    mask = jax.ShapeDtypeStruct(geometry, jnp.float32)
+    score_hlo = _score_slab.lower(gmm, slab, mask, mode="anomaly",
+                                  backend=engine.backend
+                                  ).compile().as_text()
+    check("tpu_custom_call" in score_hlo,
+          "the compiled scoring step holds a Pallas tpu_custom_call")
 
 
 # ----------------------------------------------------------------------
@@ -367,7 +314,7 @@ def _max_abs_diff(a, b) -> float:
                for x, y in zip(a, b))
 
 
-def run_sharded(args, timer: PhaseTimer) -> None:
+def run_sharded(args) -> None:
     import jax
     import jax.numpy as jnp
     from repro.api import DEM, FedGenGMM, FitConfig
@@ -375,10 +322,9 @@ def run_sharded(args, timer: PhaseTimer) -> None:
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.distributed import dem_sharded, fedgen_sharded
 
-    with timer.phase("data"):
-        ds, split = make_wadi(args.rows, args.seed)
-        k = ds.k_global
-        x_test = ds.x_test_in.astype(np.float32)
+    ds, split = make_wadi(args.rows, args.seed)
+    k = ds.k_global
+    x_test = ds.x_test_in.astype(np.float32)
     n_dev = len(jax.devices())
     mesh = jax.make_mesh((n_dev,), ("data",))
     # each chip receives only its clients' rows, straight from the host
@@ -393,13 +339,11 @@ def run_sharded(args, timer: PhaseTimer) -> None:
     cfg = FitConfig(seed=args.seed)
     dem_cfg = cfg.replace(max_iter=DEM_ROUNDS, tol=0.0)
 
-    with timer.phase("fedgen unsharded"):
-        fed = FedGenGMM(k_clients=k, k_global=k, config=cfg).run(split,
-                                                                 key=key)
-        jax.block_until_ready(fed.global_gmm)
-    with timer.phase("fedgen sharded"):
-        sharded = fedgen_sharded(mesh, key, data, mask, k, k, config=cfg)
-        jax.block_until_ready(sharded.global_gmm)
+    fed = FedGenGMM(k_clients=k, k_global=k, config=cfg).run(split,
+                                                             key=key)
+    jax.block_until_ready(fed.global_gmm)
+    sharded = fedgen_sharded(mesh, key, data, mask, k, k, config=cfg)
+    jax.block_until_ready(sharded.global_gmm)
     failures = []
 
     def soft_check(ok: bool, what: str) -> None:
@@ -435,17 +379,15 @@ def run_sharded(args, timer: PhaseTimer) -> None:
     soft_check(abs(ll_fed - ll_sh) <= 0.1,
                "fedgen_sharded global model matches unsharded held-out ll")
 
-    with timer.phase("dem unsharded"):
-        dem = DEM(k, config=dem_cfg).run(split, key=key)
-        jax.block_until_ready(dem.global_gmm)
-    with timer.phase("dem sharded"):
-        # the centers DEM's fed-kmeans init draws from the same key
-        centers = federated_kmeans(jax.random.split(key)[0],
-                                   jnp.asarray(split.data), k,
-                                   client_weights=jnp.asarray(split.mask))
-        g_sh, rounds = dem_sharded(mesh, key, data, mask, k, centers,
-                                   config=dem_cfg)
-        jax.block_until_ready(g_sh)
+    dem = DEM(k, config=dem_cfg).run(split, key=key)
+    jax.block_until_ready(dem.global_gmm)
+    # the centers DEM's fed-kmeans init draws from the same key
+    centers = federated_kmeans(jax.random.split(key)[0],
+                               jnp.asarray(split.data), k,
+                               client_weights=jnp.asarray(split.mask))
+    g_sh, rounds = dem_sharded(mesh, key, data, mask, k, centers,
+                               config=dem_cfg)
+    jax.block_until_ready(g_sh)
     g_un = dem.global_gmm
     print(f"dem: {int(dem.n_rounds)} / {int(rounds)} rounds; weights "
           f"max |diff| {_max_abs_diff([g_un.weights], [g_sh.weights]):.3e}, "
@@ -490,14 +432,11 @@ def main(argv=None) -> int:
 
     from repro.compile_cache import enable_compile_cache
     print(f"compile cache: {enable_compile_cache()}", flush=True)
-    timer = PhaseTimer()
     try:
-        (run_sharded if args.chips == 4 else run_main)(args, timer)
+        (run_sharded if args.chips == 4 else run_main)(args)
     except SmokeFailure as e:
-        timer.report()
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1
-    timer.report()
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(devices)}}))

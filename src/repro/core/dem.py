@@ -34,8 +34,10 @@ from typing import NamedTuple, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.core.config import FitConfig, is_source_list
-from repro.core.em import (e_step_stats, fit_gmm, init_from_means, m_step)
+from repro.core.config import (FitConfig, is_source_list,
+                               resolve_estep_backend)
+from repro.core.em import (computed_lanes, e_step_stats, fit_gmm,
+                           init_from_means, m_step)
 from repro.core.gmm import GMM
 from repro.core.kmeans import federated_kmeans
 from repro.core.partition import ClientSplit
@@ -240,6 +242,11 @@ class DEMStrategy:
         """One client's E-step over its own rows -> SufficientStats (the
         uplink payload; additive, so backends sum it)."""
         return e_step_stats(state.gmm, x, w, self.backend, self.chunk)
+
+    def lanes_computed(self, d: int) -> int:
+        """Feature width a client's E-step computes over."""
+        return computed_lanes(d, resolve_estep_backend(
+            self.backend, self.covariance_type == "diag"))
 
     def server_combine(self, state: DEMState, stats) -> DEMState:
         gmm = m_step(stats, state.reg_covar)

@@ -334,6 +334,17 @@ def e_step_stats_fused(gmm: GMM, x: jax.Array,
     return SufficientStats(s0, s1, s2, ll, jnp.sum(w))
 
 
+def computed_lanes(d: int, backend: str) -> int:
+    """Feature width an engine op computes over on a resolved ``backend``:
+    the fused kernels pad the ``d`` features to the lane width
+    (``repro.kernels.ops.padded_lanes``); the reference path computes
+    ``d``."""
+    if backend != "fused":
+        return d
+    from repro.kernels import ops  # local import: kernels are optional
+    return ops.padded_lanes(d)
+
+
 # Per-block statistics for the DataSource host loop. Module-level jitted so
 # every pass over a source hits the trace cache — exactly ONE block shape
 # exists per stream (prefetch_blocks pads the ragged tail to the full chunk

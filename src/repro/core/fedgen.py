@@ -25,9 +25,11 @@ from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core.config import FitConfig, is_source_list
-from repro.core.em import (EMResult, fit_gmm_bic_cfg, fit_gmm_cfg)
+from repro.core.em import (EMResult, computed_lanes, fit_gmm_bic_cfg,
+                           fit_gmm_cfg)
 from repro.core.gmm import GMM, merge_gmms
 from repro.core.partition import ClientSplit
 from repro.data.sources import DataSource, SyntheticGMMSource
@@ -36,7 +38,7 @@ from repro.data.sources import DataSource, SyntheticGMMSource
 # these re-exports keep the long-standing import path working.
 from repro.fed.ledger import (CommStats, RoundPayload, dtype_itemsize,
                               payload_floats)
-from repro.fed.runtime import run_rounds
+from repro.fed.runtime import run_rounds, slab_counters
 
 
 class FedGenResult(NamedTuple):
@@ -47,6 +49,7 @@ class FedGenResult(NamedTuple):
     #                            refit ran out-of-core (synthetic="source")
     comm: CommStats
     local_results: list[EMResult]
+    global_result: Optional[EMResult] = None  # the server refit on S
 
 
 # ----------------------------------------------------------------------
@@ -213,18 +216,20 @@ def aggregate_cfg(key: jax.Array, local_gmms: list[GMM], sizes,
     if synthetic not in ("resident", "source"):
         raise ValueError(f"synthetic must be 'resident' or 'source', "
                          f"got {synthetic!r}")
-    merged = merge_gmms(local_gmms, jnp.asarray(sizes))
     n_synth = h * sum(g.n_components for g in local_gmms)
-    k_sample, k_fit = jax.random.split(key)
-    if synthetic == "source":
-        synthetic = SyntheticGMMSource(merged, n_synth, k_sample)
-    else:
-        synthetic = merged.sample(k_sample, n_synth)
-    if k_global is not None:
-        res = fit_gmm_cfg(k_fit, synthetic, k_global, config)
-    else:
-        assert k_candidates is not None, "need k_global or k_candidates"
-        res, _ = fit_gmm_bic_cfg(k_fit, synthetic, k_candidates, config)
+    with TraceAnnotation("repro.fedgen.merge_sample", rows=n_synth):
+        merged = merge_gmms(local_gmms, jnp.asarray(sizes))
+        k_sample, k_fit = jax.random.split(key)
+        if synthetic == "source":
+            synthetic = SyntheticGMMSource(merged, n_synth, k_sample)
+        else:
+            synthetic = merged.sample(k_sample, n_synth)
+    with TraceAnnotation("repro.fedgen.refit"):
+        if k_global is not None:
+            res = fit_gmm_cfg(k_fit, synthetic, k_global, config)
+        else:
+            assert k_candidates is not None, "need k_global or k_candidates"
+            res, _ = fit_gmm_bic_cfg(k_fit, synthetic, k_candidates, config)
     return res, synthetic
 
 
@@ -285,35 +290,41 @@ class FedGenStrategy:
         server sees it — for :class:`~repro.fed.transforms.GaussianDP`
         that is the paper-§4.4 one-shot DP release, the whole budget
         spent in this one round."""
-        if backend.kind == "sources":
-            local_results = train_locals_sources_cfg(
-                state["k_local"], backend.sources, self.config,
-                k=self.k_clients, k_candidates=self.k_candidates)
-            local_gmms = [r.gmm for r in local_results]
-            sizes = backend.sizes
-        elif backend.kind == "split":
-            split = backend.split
-            sizes = split.sizes
-            if self.k_clients is not None:
-                stacked, lls, iters = train_locals_cfg(
-                    state["k_local"], backend.data, backend.mask,
-                    self.k_clients, self.config)
-                local_gmms = [
-                    GMM(stacked.weights[i], stacked.means[i], stacked.covs[i])
-                    for i in range(split.data.shape[0])]
-                local_results = [
-                    EMResult(g, lls[i], iters[i], jnp.array(True))
-                    for i, g in enumerate(local_gmms)]
-            else:
-                assert self.k_candidates is not None, \
-                    "need k_clients or k_candidates"
-                local_results = train_locals_bic_cfg(
-                    state["k_local"], split, self.k_candidates, self.config)
-                local_gmms = [r.gmm for r in local_results]
-        else:
+        if backend.kind not in ("sources", "split"):
             raise TypeError(
                 "FedGenStrategy runs ClientSplit or source-list clients; "
                 "the mesh variant is repro.distributed.fedgen_sharded")
+        counters = slab_counters(backend, computed_lanes(
+            int(backend.dim), self.config.resolved_estep()))
+        sizes = backend.sizes
+        if backend.kind == "split" and self.k_clients is not None:
+            with TraceAnnotation("repro.fedgen.local", **counters):
+                stacked, lls, iters = train_locals_cfg(
+                    state["k_local"], backend.data, backend.mask,
+                    self.k_clients, self.config)
+            with TraceAnnotation("repro.fedgen.unstack"):
+                local_gmms = [
+                    GMM(stacked.weights[i], stacked.means[i], stacked.covs[i])
+                    for i in range(backend.num_clients)]
+                local_results = [
+                    EMResult(g, lls[i], iters[i], jnp.array(True))
+                    for i, g in enumerate(local_gmms)]
+        else:
+            # each client is fitted on its own rows, off the padded slab
+            counters.pop("rows_computed", None)
+            with TraceAnnotation("repro.fedgen.local", **counters):
+                if backend.kind == "sources":
+                    local_results = train_locals_sources_cfg(
+                        state["k_local"], backend.sources, self.config,
+                        k=self.k_clients, k_candidates=self.k_candidates)
+                else:
+                    assert self.k_candidates is not None, \
+                        "need k_clients or k_candidates"
+                    local_results = train_locals_bic_cfg(
+                        state["k_local"], backend.split,
+                        self.k_candidates, self.config)
+            with TraceAnnotation("repro.fedgen.unstack"):
+                local_gmms = [r.gmm for r in local_results]
 
         if transform is not None:
             # the uplink seam for the one-shot round: each client's
@@ -347,7 +358,8 @@ class FedGenStrategy:
     def finalize(self, state, n_rounds, converged,
                  comm: CommStats) -> FedGenResult:
         return FedGenResult(state["res"].gmm, state["local_gmms"],
-                            state["synth"], comm, state["local_results"])
+                            state["synth"], comm, state["local_results"],
+                            state["res"])
 
 
 def fedgengmm_cfg(key: jax.Array, clients, config: FitConfig,

@@ -30,6 +30,10 @@ Execution modes (picked per backend, never per strategy):
   inside jit) with the same state transitions, mirroring the engine's
   ``host_em_loop`` semantics (Python-float convergence arithmetic).
 
+The phases of ``run_rounds`` are ``repro.rounds.*`` profiler spans, and
+a round's phases inside the jitted loop are named scopes (DESIGN.md
+§13).
+
 This module deliberately imports nothing from ``repro.core`` at module
 top (only ``repro.data.sources``, which is itself repro-free), so
 ``core/fedgen.py`` and ``core/dem.py`` can import the runtime without
@@ -45,6 +49,7 @@ from typing import Any, Optional, Protocol, Sequence, runtime_checkable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import PartitionSpec as P
 
 from repro.data.sources import DataSource
@@ -79,6 +84,9 @@ class FederationStrategy(Protocol):
       not-converged instead of spinning to the round budget. Strategies
       with that semantics implement both predicates; the driver falls
       back to ``not converged`` when ``keep_going`` is absent.
+    - ``lanes_computed(d) -> int`` (optional) — the feature width
+      ``local_step`` computes over (d, or d padded by a kernel), for the
+      round loop's profiler counters; left out of them when absent.
     - ``round_payload(backend, state) -> RoundPayload`` — what one round
       moves; the driver multiplies by the realized round count.
     - ``finalize(state, n_rounds, converged, comm) -> result``.
@@ -396,6 +404,34 @@ class ShardedClients:
                   tk, tp)
 
 
+def slab_counters(backend, lanes_computed: Optional[int] = None,
+                  cohort_size: Optional[int] = None) -> dict:
+    """Counters of the client data one round computes over, for a
+    profiler span: ``clients`` (the cohort's size when one is sampled);
+    ``rows``, their real rows; ``rows_computed``, the rows of the padded
+    ``(clients, N, d)`` slab; ``lanes``, the feature width d;
+    ``lanes_computed``, the width the clients' op computes over, as the
+    layer that picks its backend gives it (left out where none does).
+    Taken from shapes and host arrays alone: a count that lives on the
+    device is left out, never fetched (that would wait for the device).
+    So are a sampled cohort's rows, which change from round to round, and
+    ``rows_computed`` for source clients, whose block padding the engine
+    owns."""
+    d = int(backend.dim)
+    c = int(backend.num_clients if cohort_size is None else cohort_size)
+    out = {"clients": c, "lanes": d}
+    if lanes_computed is not None:
+        out["lanes_computed"] = int(lanes_computed)
+    if backend.kind != "sources":
+        out["rows_computed"] = c * int(backend.data.shape[1])
+    if cohort_size is None:
+        sizes = backend.sizes if backend.kind == "sources" \
+            else getattr(getattr(backend, "split", None), "sizes", None)
+        if isinstance(sizes, (np.ndarray, list, tuple)):
+            out["rows"] = int(np.sum(sizes))
+    return out
+
+
 def make_backend(clients, mesh=None, axis: str = "data"):
     """THE client dispatch: ClientSplit -> :class:`SplitClients`, a list
     of DataSources -> :class:`SourceClients`, ``(data, mask)`` arrays with
@@ -428,13 +464,22 @@ def _round(strategy, state, backend, cohort=None, weights=None,
     come from the driver's sampler and straggler policy (None = full
     participation, everyone on time); ``transform``/``tparams``/``rkey``
     from the driver's uplink-transform seam (§11; ``rkey`` is already
-    folded per round)."""
-    total = backend.reduce_clients(strategy.local_step, state, cohort,
-                                   weights, transform=transform,
-                                   tparams=tparams, tkey=rkey)
-    if transform is not None:
-        total = transform.finish(total)
-    return strategy.server_combine(state, total)
+    folded per round). The named scopes mark the round's phases in the
+    compiled program's op metadata, for a profiler's op view: inside
+    ``reduce``, ``client_step`` is one client's update and the rest is
+    the sum over clients."""
+    def client_step(state, x, w, idx):
+        with jax.named_scope("client_step"):
+            return strategy.local_step(state, x, w, idx)
+
+    with jax.named_scope("reduce"):
+        total = backend.reduce_clients(client_step, state, cohort,
+                                       weights, transform=transform,
+                                       tparams=tparams, tkey=rkey)
+        if transform is not None:
+            total = transform.finish(total)
+    with jax.named_scope("combine"):
+        return strategy.server_combine(state, total)
 
 
 def _keep_going(strategy, state):
@@ -613,8 +658,16 @@ def run_rounds(strategy, clients, *, key: Optional[jax.Array] = None,
                 "strategies take no straggler policy")
         dkey = jax.random.key(int(getattr(stragglers, "seed", 0)))
     if state0 is None:
-        state0 = strategy.init_state(key, backend)
+        with TraceAnnotation("repro.rounds.init"):
+            state0 = strategy.init_state(key, backend)
 
+    cohort_size = None if sampler is None else sampler.cohort_size
+    # the loop spans' counters; the strategy, which picks the clients'
+    # backend, gives the width they compute over
+    lanes = getattr(strategy, "lanes_computed", None)
+    slab = None if one_shot else slab_counters(
+        backend, None if lanes is None else lanes(int(backend.dim)),
+        cohort_size)
     if one_shot:
         if transform is not None:
             state = strategy.run_once(state0, backend,
@@ -625,46 +678,51 @@ def run_rounds(strategy, clients, *, key: Optional[jax.Array] = None,
         rounds, n_rounds, converged = 1, jnp.asarray(1), True
     elif backend.host:
         def host_round(state, rnd):
-            cohort, weights = _cohort_and_weights(
-                sampler, stragglers, backend, skey, dkey, rnd)
-            if cohort is not None:
-                cohort = np.asarray(cohort)
-            rkey = None if transform is None \
-                else jax.random.fold_in(tkey, rnd)
-            return _round(strategy, state, backend, cohort, weights,
-                          transform, tparams, rkey)
+            with TraceAnnotation("repro.rounds.round"):
+                cohort, weights = _cohort_and_weights(
+                    sampler, stragglers, backend, skey, dkey, rnd)
+                if cohort is not None:
+                    cohort = np.asarray(cohort)
+                rkey = None if transform is None \
+                    else jax.random.fold_in(tkey, rnd)
+                return _round(strategy, state, backend, cohort, weights,
+                              transform, tparams, rkey)
 
-        state = host_round(state0, 0)
-        it = 1
-        while it < max_rounds and bool(_keep_going(strategy, state)):
-            state = host_round(state, it)
-            it += 1
-        rounds, n_rounds = it, jnp.asarray(it)
-        converged = bool(strategy.converged(state))
+        with TraceAnnotation("repro.rounds.loop", **slab):
+            state = host_round(state0, 0)
+            it = 1
+            while it < max_rounds and bool(_keep_going(strategy, state)):
+                state = host_round(state, it)
+                it += 1
+            rounds, n_rounds = it, jnp.asarray(it)
+            converged = bool(strategy.converged(state))
     else:
-        state, n_rounds = _iterate_jit(strategy, backend, state0,
-                                       max_rounds, sampler, stragglers,
-                                       transform, skey, dkey, tkey,
-                                       tparams)
-        rounds = int(n_rounds)
-        converged = bool(strategy.converged(state))
+        with TraceAnnotation("repro.rounds.loop", **slab):
+            state, n_rounds = _iterate_jit(strategy, backend, state0,
+                                           max_rounds, sampler, stragglers,
+                                           transform, skey, dkey, tkey,
+                                           tparams)
+            rounds = int(n_rounds)
+            converged = bool(strategy.converged(state))
 
-    # Optional once-per-run epilogue (e.g. FedKMeans rescoring its final
-    # centers); runs eagerly after the loop, before the ledger is drawn up
-    # so the strategy's RoundPayload can account for it.
-    post = getattr(strategy, "post_rounds", None)
-    if post is not None and not one_shot:
-        state = post(state, backend)
+    with TraceAnnotation("repro.rounds.finalize", rounds=rounds):
+        # Optional once-per-run epilogue (e.g. FedKMeans rescoring its
+        # final centers); runs eagerly after the loop, before the ledger
+        # is drawn up so the strategy's RoundPayload can account for it.
+        post = getattr(strategy, "post_rounds", None)
+        if post is not None and not one_shot:
+            state = post(state, backend)
 
-    ledger_backend = backend if sampler is None \
-        else _CohortView(backend, sampler.cohort_size)
-    payload = strategy.round_payload(ledger_backend, state)
-    if transform is not None:
-        # transform-aware ledger: the uplink direction carries the wire
-        # dtype the transform produced, and the accountant's per-round
-        # spend scales by the realized rounds into epsilon_spent
-        payload = payload._replace(
-            uplink_itemsize=transform.wire_itemsize(payload.itemsize),
-            epsilon_per_round=float(transform.epsilon_per_round()))
-    comm = payload.totals(rounds)
-    return strategy.finalize(state, n_rounds, converged, comm)
+        ledger_backend = backend if sampler is None \
+            else _CohortView(backend, sampler.cohort_size)
+        payload = strategy.round_payload(ledger_backend, state)
+        if transform is not None:
+            # transform-aware ledger: the uplink direction carries the
+            # wire dtype the transform produced, and the accountant's
+            # per-round spend scales by the realized rounds into
+            # epsilon_spent
+            payload = payload._replace(
+                uplink_itemsize=transform.wire_itemsize(payload.itemsize),
+                epsilon_per_round=float(transform.epsilon_per_round()))
+        comm = payload.totals(rounds)
+        return strategy.finalize(state, n_rounds, converged, comm)

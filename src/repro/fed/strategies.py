@@ -42,7 +42,7 @@ import jax.numpy as jnp
 
 from repro.core.config import FitConfig, is_source_list, resolve_backend
 from repro.core.dem import DEMStrategy, _resolve_init, max_separated_centers
-from repro.core.em import e_step_stats, m_step
+from repro.core.em import computed_lanes, e_step_stats, m_step
 from repro.core.gmm import GMM
 from repro.core.kmeans import federated_kmeans, lloyd_round_stats
 from repro.core.partition import ClientSplit
@@ -297,6 +297,10 @@ class FedKMeansStrategy:
         dt = centers.dtype
         inf = jnp.array(jnp.inf, dt)
         return FedKMeansState(centers, inf, inf, jnp.asarray(self.tol, dt))
+
+    def lanes_computed(self, d: int) -> int:
+        """Feature width a client's assignment sweep computes over."""
+        return computed_lanes(d, self.assign_backend)
 
     def local_step(self, state: FedKMeansState, x, w, idx):
         return lloyd_round_stats(state.centers, x, w, self.assign_backend,

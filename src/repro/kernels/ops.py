@@ -1,6 +1,6 @@
 """Public wrappers around the Pallas kernels.
 
-Handle padding to TPU tile boundaries (lanes = 128, tunable N/K blocks)
+Handle padding to TPU tile boundaries (``LANES`` = 128, tunable N/K blocks)
 and parameter re-packing into the matmul-identity form. With
 ``interpret=None`` a kernel compiles for the TPU (Mosaic) when JAX's
 default backend is a TPU, and runs in Pallas interpret mode anywhere else:
@@ -22,6 +22,9 @@ from repro.kernels.kmeans_assign import kmeans_assign_pallas
 
 LOG_2PI = 1.8378770664093453
 _NEG_BIG = -1e30
+#: the TPU's vector lane width: the kernels pad features and components
+#: to a multiple of it
+LANES = 128
 
 
 def _auto_interpret(interpret):
@@ -32,6 +35,11 @@ def _auto_interpret(interpret):
 
 def _round_up(v: int, m: int) -> int:
     return (v + m - 1) // m * m
+
+
+def padded_lanes(d: int) -> int:
+    """Width a kernel computes over for ``d`` features or components."""
+    return _round_up(d, LANES)
 
 
 def _pack_params(means, variances, log_weights, d_pad, k_pad, pad_c=0.0):
@@ -59,7 +67,7 @@ def gmm_logpdf(x: jax.Array, means: jax.Array, variances: jax.Array,
     interpret = _auto_interpret(interpret)
     n, d = x.shape
     k = means.shape[0]
-    n_pad, k_pad, d_pad = _round_up(n, block_n), _round_up(k, block_k), _round_up(d, 128)
+    n_pad, k_pad, d_pad = _round_up(n, block_n), _round_up(k, block_k), padded_lanes(d)
     a, b, c = _pack_params(means, variances, log_weights, d_pad, k_pad)
     xp = jnp.zeros((n_pad, d_pad), jnp.float32).at[:n, :d].set(x)
     out = gmm_logpdf_pallas(xp, a, b, c, block_n=block_n, block_k=block_k,
@@ -76,8 +84,8 @@ def estep_stats(x: jax.Array, means: jax.Array, variances: jax.Array,
     n, d = x.shape
     k = means.shape[0]
     n_pad = _round_up(n, block_n)
-    d_pad = _round_up(d, 128)
-    k_pad = _round_up(k, 128)
+    d_pad = padded_lanes(d)
+    k_pad = padded_lanes(k)
     a, b, c = _pack_params(means, variances, log_weights, d_pad, k_pad,
                            pad_c=_NEG_BIG)
     xp = jnp.zeros((n_pad, d_pad), jnp.float32).at[:n, :d].set(x)
@@ -95,8 +103,8 @@ def kmeans_assign(x: jax.Array, centers: jax.Array, *,
     n, d = x.shape
     k = centers.shape[0]
     n_pad = _round_up(n, block_n)
-    d_pad = _round_up(d, 128)
-    k_pad = _round_up(k, 128)
+    d_pad = padded_lanes(d)
+    k_pad = padded_lanes(k)
     xp = jnp.zeros((n_pad, d_pad), jnp.float32).at[:n, :d].set(x)
     ct = jnp.zeros((d_pad, k_pad), jnp.float32).at[:d, :k].set(centers.T)
     c2 = jnp.full((1, k_pad), 1e30, jnp.float32).at[0, :k].set(

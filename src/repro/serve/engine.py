@@ -13,6 +13,8 @@ attached model store, finish a pending hot swap if the pool has drained,
 admit queued requests into free slots, score the ``(slots,
 rows_per_slot, d)`` slab in one jitted call (slab and mask buffers are
 donated — XLA reuses their memory for the outputs), and harvest/retire.
+Each of those phases is a ``repro.serve.*`` profiler span (DESIGN.md
+§13).
 Requests longer than ``rows_per_slot`` stream through their slot across
 micro-batches; short ones are padded to the static shape, so the hot
 path compiles exactly once per ``(slots, rows_per_slot, d, K, mode,
@@ -42,6 +44,7 @@ from typing import List, Optional, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.config import resolve_backend
 from repro.core.em import _log_prob_block
@@ -113,6 +116,7 @@ class ScoringEngine:
             raise TypeError(f"config must be a ScoreConfig, "
                             f"got {type(self.config).__name__}")
         self._store = store
+        # (request, time.perf_counter() at submit), FIFO
         self._queue: deque = deque()
         self._pending: Optional[tuple] = None     # (gmm, version)
         self._pending_since: Optional[float] = None
@@ -184,7 +188,7 @@ class ScoringEngine:
             self.swaps += 1
             return
         if self._pending_since is None:
-            self._pending_since = time.time()
+            self._pending_since = time.perf_counter()
         self._pending = (gmm, version)
 
     def _finish_swap_if_drained(self) -> None:
@@ -192,7 +196,8 @@ class ScoringEngine:
             gmm, version = self._pending
             self._pending = None
             if self._pending_since is not None:
-                self.swap_pauses.append(time.time() - self._pending_since)
+                self.swap_pauses.append(time.perf_counter()
+                                        - self._pending_since)
                 self._pending_since = None
             self._set_model(gmm, version)
             self.swaps += 1
@@ -249,30 +254,35 @@ class ScoringEngine:
                 f"request {request.rid}: rows have dim "
                 f"{request.rows.shape[1]}, the served model expects "
                 f"{self.dim}")
-        self._queue.append(request)
+        self._queue.append((request, time.perf_counter()))
 
-    def _admit(self, results: List[ScoreResult]) -> None:
-        """Fill free slots from the queue (FIFO). Blocked entirely while
-        a swap is pending — that is the drain half of the protocol.
-        Zero-row requests retire immediately (they still consume an
-        admission, so their version tag honors the swap boundary)."""
+    def _admit(self, results: List[ScoreResult]) -> tuple[int, float]:
+        """Fill free slots from the queue (FIFO) -> (requests admitted,
+        their summed seconds in the queue). Blocked entirely while a swap
+        is pending — that is the drain half of the protocol. Zero-row
+        requests retire immediately (they still consume an admission, so
+        their version tag honors the swap boundary)."""
+        admitted, waited = 0, 0.0
         if self._pending is not None:
-            return
+            return admitted, waited
+        now = time.perf_counter()
         while self._queue:
-            head = self._queue[0]
+            head, submitted = self._queue[0]
             if head.num_rows == 0:
-                self._queue.popleft()
-                entry = InFlight(head, time.time(), self._version)
                 trailing = ((int(self._gmm.n_components),)
                             if self.config.mode == "responsibilities"
                             else ())
-                results.append(self._pool.retire_empty(entry, trailing))
+                results.append(self._pool.retire_empty(
+                    InFlight(head, submitted, self._version), trailing))
                 self.completed += 1
-                continue
-            if self._pool.free == 0:
-                return
-            self._pool.admit(InFlight(head, time.time(), self._version))
+            elif self._pool.free == 0:
+                break
+            else:
+                self._pool.admit(InFlight(head, submitted, self._version))
             self._queue.popleft()
+            admitted += 1
+            waited += now - submitted
+        return admitted, waited
     # -- micro-batches --------------------------------------------------
 
     def step(self) -> List[ScoreResult]:
@@ -283,27 +293,44 @@ class ScoringEngine:
         harvest/retire -> finish the swap again if those retirements
         drained the pool (so the stall never lasts longer than the drain
         itself). A fully idle step returns ``[]``."""
-        self.steps += 1
-        self._poll_store()
-        self._finish_swap_if_drained()
-        results: List[ScoreResult] = []
-        self._admit(results)
-        active = self._pool.stage()
-        if active:
-            with warnings.catch_warnings():
-                # Donation is deliberate (both buffers are rebuilt every
-                # micro-batch); XLA notes the shapes it cannot alias.
-                warnings.filterwarnings(
-                    "ignore", message="Some donated buffers were not usable")
-                out = _score_slab(self._gmm, jnp.asarray(self._pool.slab),
-                                  jnp.asarray(self._pool.mask),
-                                  mode=self.config.mode,
-                                  backend=self._backend)
-            finished = self._pool.harvest(np.asarray(out), active)
-            self.completed += len(finished)
-            results.extend(finished)
-        self._finish_swap_if_drained()
-        return results
+        with TraceAnnotation("repro.serve.step"):
+            self.steps += 1
+            results: List[ScoreResult] = []
+            with TraceAnnotation("repro.serve.admit"):
+                self._poll_store()
+                self._finish_swap_if_drained()
+                admitted, waited = self._admit(results)
+            pool = self._pool
+            with TraceAnnotation("repro.serve.stage", admitted=admitted,
+                                 queue_wait_us=int(waited * 1e6),
+                                 queued=len(self._queue)):
+                active = pool.stage()
+            if active:
+                with TraceAnnotation(
+                        "repro.serve.put", rows=pool.staged_rows,
+                        rows_computed=pool.slots * pool.rows_per_slot,
+                        h2d_bytes=pool.slab.nbytes + pool.mask.nbytes):
+                    slab = jnp.asarray(pool.slab)
+                    mask = jnp.asarray(pool.mask)
+                with TraceAnnotation("repro.serve.score"), \
+                        warnings.catch_warnings():
+                    # Donation is deliberate (both buffers are rebuilt
+                    # every micro-batch); XLA notes the shapes it cannot
+                    # alias.
+                    warnings.filterwarnings(
+                        "ignore",
+                        message="Some donated buffers were not usable")
+                    out = _score_slab(self._gmm, slab, mask,
+                                      mode=self.config.mode,
+                                      backend=self._backend)
+                with TraceAnnotation("repro.serve.fetch"):
+                    out = np.asarray(out)
+                with TraceAnnotation("repro.serve.harvest"):
+                    finished = pool.harvest(out, active)
+                self.completed += len(finished)
+                results.extend(finished)
+            self._finish_swap_if_drained()
+            return results
 
     def drain(self) -> List[ScoreResult]:
         """Step until every submitted request has retired -> all results

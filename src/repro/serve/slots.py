@@ -31,7 +31,8 @@ class InFlight:
     """Host bookkeeping of one admitted request: the cursor into its rows
     and the output chunks harvested so far. ``version`` is pinned at
     admission — the swap protocol guarantees it is the version of every
-    model that touches this request."""
+    model that touches this request. ``submitted_s`` is the request's
+    ``time.perf_counter()`` at submit."""
 
     request: ScoreRequest
     submitted_s: float
@@ -69,6 +70,8 @@ class SlotPool:
         self.dim = dim
         self.slab = np.zeros((slots, rows_per_slot, dim), np.float32)
         self.mask = np.zeros((slots, rows_per_slot), np.float32)
+        #: real (unpadded) rows the last :meth:`stage` wrote
+        self.staged_rows = 0
         self._entries: List[Optional[InFlight]] = [None] * slots
 
     # -- occupancy ------------------------------------------------------
@@ -106,6 +109,7 @@ class SlotPool:
         indices this micro-batch. Inactive slots get mask 0; their stale
         slab rows are dead weight the mask cancels."""
         active = []
+        self.staged_rows = 0
         for s, entry in enumerate(self._entries):
             if entry is None:
                 self.mask[s] = 0.0
@@ -117,6 +121,7 @@ class SlotPool:
             self.slab[s, take:] = 0.0
             self.mask[s, :take] = 1.0
             self.mask[s, take:] = 0.0
+            self.staged_rows += take
             active.append(s)
         return active
 
@@ -127,7 +132,7 @@ class SlotPool:
         cursors, and retire every request whose rows are exhausted ->
         the finished :class:`ScoreResult` list (slots are freed)."""
         results: List[ScoreResult] = []
-        now = time.time()
+        now = time.perf_counter()
         for s in active:
             entry = self._entries[s]
             take = min(entry.request.num_rows - entry.cursor,
@@ -154,4 +159,4 @@ class SlotPool:
             rid=entry.request.rid,
             scores=np.zeros((0,) + tuple(trailing), np.float32),
             model_version=entry.version,
-            latency_s=time.time() - entry.submitted_s)
+            latency_s=time.perf_counter() - entry.submitted_s)

@@ -1,0 +1,262 @@
+"""The program's own profiler spans and counters (DESIGN.md §13).
+
+Each phase of a scoring step, of FedGenGMM and of the round driver is a
+``jax.profiler.TraceAnnotation`` named ``repro.*``, its counters among
+the annotation's arguments. A CPU trace of one call of each holds every
+span, nested as the code nests them, with counters equal to what the
+shapes give. A traced call returns what an untraced one returns. Inside
+the jitted round loop the phases are ``jax.named_scope``\\ s, which the
+lowered program's locations carry."""
+import glob
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.api import DEM, FedGenGMM, FitConfig
+from repro.core.dem import DEMStrategy
+from repro.core.gmm import GMM
+from repro.core.partition import partition
+from repro.data.sources import ArraySource
+from repro.core.em import computed_lanes
+from repro.fed.runtime import _iterate_jit, make_backend, slab_counters
+from repro.fed.strategies import FedKMeansStrategy
+from repro.kernels.ops import LANES, padded_lanes
+from repro.serve import ScoreConfig, ScoreRequest, ScoringEngine
+from conftest import planted_gmm_data
+
+D, K, CLIENTS = 4, 3, 3
+
+
+def traced(tmp_path, fn):
+    """Run ``fn`` under the profiler -> (its value, the ``repro.*`` host
+    spans as (name, start_ns, end_ns, counters), outer before inner)."""
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    spans = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+              dict(ev.stats))
+             for plane in data.planes if not plane.name.startswith("/device:")
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith("repro.")]
+    return out, sorted(spans, key=lambda s: (s[1], -s[2]))
+
+
+def parents(spans) -> dict:
+    """{span name: the names of the innermost spans that enclose it}."""
+    out = {}
+    for i, (name, a, b, _) in enumerate(spans):
+        around = [s[0] for s in spans[:i] if s[1] <= a and b <= s[2]]
+        out.setdefault(name, set()).add(around[-1] if around else None)
+    return out
+
+
+def counters(spans, name) -> list:
+    return [s[3] for s in spans if s[0] == name]
+
+
+@pytest.fixture(scope="module")
+def split():
+    x, y, _ = planted_gmm_data(np.random.default_rng(3), n=600, d=D, k=K,
+                               spread=5.0)
+    return partition(np.random.default_rng(4), x, y, CLIENTS, "dirichlet",
+                     1.0)
+
+
+# -- serving -------------------------------------------------------------
+
+SLOTS, ROWS_PER_SLOT = 4, 8
+# the fifth request has no rows and retires at admission; the sixth finds
+# every slot taken
+SIZES = (3, 8, 20, 0, 5, 7)
+
+
+def _engine():
+    rng = np.random.default_rng(0)
+    gmm = GMM(jnp.full((K,), 1.0 / K), jnp.asarray(rng.normal(size=(K, D)),
+                                                   jnp.float32),
+              jnp.ones((K, D), jnp.float32))
+    engine = ScoringEngine(gmm, ScoreConfig(mode="anomaly", slots=SLOTS,
+                                            rows_per_slot=ROWS_PER_SLOT))
+    rows = rng.normal(size=(max(SIZES), D)).astype(np.float32)
+    engine.run([ScoreRequest(-1, rows[:2])])  # compile outside the trace
+    return engine, [ScoreRequest(i, rows[:n]) for i, n in enumerate(SIZES)]
+
+
+def test_serving_step_spans_nest_and_count(tmp_path):
+    engine, requests = _engine()
+    for r in requests:
+        engine.submit(r)
+    waited = 0.02
+    time.sleep(waited)
+    _, spans = traced(tmp_path, engine.step)
+    tree = parents(spans)
+    assert tree.pop("repro.serve.step") == {None}
+    assert tree == {name: {"repro.serve.step"} for name in (
+        "repro.serve.admit", "repro.serve.stage", "repro.serve.put",
+        "repro.serve.score", "repro.serve.fetch", "repro.serve.harvest")}
+    stage, = counters(spans, "repro.serve.stage")
+    assert (stage["admitted"], stage["queued"]) == (5, 1)
+    assert stage["queue_wait_us"] >= 5 * waited * 1e6
+    put, = counters(spans, "repro.serve.put")
+    assert put == {"rows": 3 + 8 + 8 + 5,
+                   "rows_computed": SLOTS * ROWS_PER_SLOT,
+                   "h2d_bytes": 4 * SLOTS * ROWS_PER_SLOT * (D + 1)}
+
+
+def test_a_step_without_work_stages_nothing(tmp_path):
+    engine, _ = _engine()
+    _, spans = traced(tmp_path, engine.step)
+    assert [s[0] for s in spans] == ["repro.serve.step", "repro.serve.admit",
+                                     "repro.serve.stage"]
+    assert counters(spans, "repro.serve.stage") == [
+        {"admitted": 0, "queue_wait_us": 0, "queued": 0}]
+
+
+def test_traced_scores_equal_untraced(tmp_path):
+    scores = []
+    for trace in (False, True):
+        engine, requests = _engine()
+        run = lambda: {r.rid: r.scores for r in engine.run(requests)}
+        scores.append(traced(tmp_path / "t", run)[0] if trace else run())
+    assert scores[0].keys() == scores[1].keys() == set(range(len(SIZES)))
+    for rid, s in scores[0].items():
+        np.testing.assert_array_equal(s, scores[1][rid])
+
+
+def test_latency_runs_from_submit():
+    engine, requests = _engine()
+    engine.submit(requests[0])
+    time.sleep(0.02)
+    result, = engine.step()
+    assert result.latency_s >= 0.02
+
+
+# -- fitting -------------------------------------------------------------
+
+def _slab(split):
+    """The counters of a fit on the CPU, where the E-step runs the
+    reference path over the d features themselves."""
+    return {"clients": CLIENTS, "rows": int(np.sum(split.sizes)),
+            "rows_computed": CLIENTS * split.data.shape[1], "lanes": D,
+            "lanes_computed": D}
+
+
+def test_lanes_computed_is_the_lane_multiple():
+    assert LANES == 128
+    assert [padded_lanes(d) for d in (1, 38, 84, 128, 129)] == \
+        [128, 128, 128, 128, 256]
+
+
+@pytest.mark.parametrize("strategy,lanes", [
+    (DEMStrategy(k=K, backend="fused"), LANES),
+    (DEMStrategy(k=K, backend="reference"), D),
+    # the fused E-step has no full covariance: the reference path runs
+    (DEMStrategy(k=K, backend="fused", covariance_type="full"), D),
+    (FedKMeansStrategy(k=K, assign_backend="fused"), LANES),
+    (FedKMeansStrategy(k=K, assign_backend="reference"), D),
+])
+def test_lanes_computed_follows_the_backend(strategy, lanes):
+    assert strategy.lanes_computed(D) == lanes
+
+
+@pytest.mark.parametrize("backend,lanes", [("fused", LANES),
+                                           ("reference", D)])
+def test_fedgen_lanes_follow_its_estep(backend, lanes):
+    assert computed_lanes(
+        D, FitConfig(backend=backend).resolved_estep()) == lanes
+
+
+def test_dem_reference_backend_counts_its_own_width(tmp_path, split):
+    dem = DEM(K, config=FitConfig(max_iter=3, backend="reference"))
+    _, spans = traced(tmp_path, lambda: dem.run(split,
+                                                 key=jax.random.key(0)))
+    assert counters(spans, "repro.rounds.loop") == [_slab(split)]
+
+
+def test_fedgen_spans_nest_and_count(tmp_path, split):
+    fed = FedGenGMM(k_clients=K, k_global=K, h=20,
+                    config=FitConfig(max_iter=5))
+    res, spans = traced(tmp_path, lambda: fed.run(split,
+                                                  key=jax.random.key(0)))
+    assert parents(spans) == {name: {None} for name in (
+        "repro.rounds.init", "repro.fedgen.local", "repro.fedgen.unstack",
+        "repro.fedgen.merge_sample", "repro.fedgen.refit",
+        "repro.rounds.finalize")}
+    assert [s[0] for s in spans] == [
+        "repro.rounds.init", "repro.fedgen.local", "repro.fedgen.unstack",
+        "repro.fedgen.merge_sample", "repro.fedgen.refit",
+        "repro.rounds.finalize"]
+    assert counters(spans, "repro.fedgen.local") == [_slab(split)]
+    assert counters(spans, "repro.fedgen.merge_sample") == [
+        {"rows": 20 * CLIENTS * K}]
+    assert counters(spans, "repro.rounds.finalize") == [{"rounds": 1}]
+    # the server refit's own result rides along
+    assert res.global_result.gmm is res.global_gmm
+    assert 1 <= int(res.global_result.n_iter) <= 5
+
+
+def test_fedgen_traced_equals_untraced(tmp_path, split):
+    fed = FedGenGMM(k_clients=K, k_global=K, h=20,
+                    config=FitConfig(max_iter=5))
+    plain = fed.run(split, key=jax.random.key(1))
+    res, _ = traced(tmp_path, lambda: fed.run(split, key=jax.random.key(1)))
+    for a, b in zip(jax.tree.leaves(plain), jax.tree.leaves(res)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_dem_spans_nest_and_count(tmp_path, split):
+    dem = DEM(K, config=FitConfig(max_iter=6))
+    res, spans = traced(tmp_path, lambda: dem.run(split,
+                                                  key=jax.random.key(0)))
+    assert parents(spans) == {name: {None} for name in (
+        "repro.rounds.init", "repro.rounds.loop", "repro.rounds.finalize")}
+    assert counters(spans, "repro.rounds.loop") == [_slab(split)]
+    assert counters(spans, "repro.rounds.finalize") == [
+        {"rounds": int(res.n_rounds)}]
+
+
+def test_host_rounds_nest_in_the_loop(tmp_path, split):
+    sources = [ArraySource(np.asarray(split.data[c, :n]))
+               for c, n in enumerate(split.sizes)]
+    dem = DEM(K, config=FitConfig(max_iter=3, chunk_size=64))
+    res, spans = traced(tmp_path, lambda: dem.run(sources,
+                                                  key=jax.random.key(0)))
+    assert parents(spans) == {"repro.rounds.init": {None},
+                              "repro.rounds.loop": {None},
+                              "repro.rounds.round": {"repro.rounds.loop"},
+                              "repro.rounds.finalize": {None}}
+    assert len(counters(spans, "repro.rounds.round")) == int(res.n_rounds)
+    # source clients are not padded into a slab
+    slab = _slab(split)
+    del slab["rows_computed"]
+    assert counters(spans, "repro.rounds.loop") == [slab]
+
+
+def test_a_sampled_cohort_counts_its_slab_only(split):
+    backend = make_backend(split)
+    assert slab_counters(backend, LANES, cohort_size=2) == {
+        "clients": 2, "rows_computed": 2 * split.data.shape[1], "lanes": D,
+        "lanes_computed": LANES}
+    # where no layer gives the computed width, the counter is left out
+    assert "lanes_computed" not in slab_counters(backend)
+
+
+def test_round_loop_carries_its_named_scopes(split):
+    strategy = DEMStrategy(k=K)
+    backend = make_backend(split)
+    state0 = strategy.init_state(jax.random.key(0), backend)
+    text = _iterate_jit.lower(strategy, backend, state0, 4).as_text(
+        debug_info=True)
+    # the bootstrap round and the loop's body: each client's update inside
+    # the reduce, the sum over clients, the server's combine
+    for body in ("jit(_iterate_jit)/", "jit(_iterate_jit)/while/body/"):
+        for scope in ("reduce/vmap(client_step)/", "reduce/reduce_sum",
+                      "combine/"):
+            assert f'"{body}{scope}' in text, body + scope
