@@ -34,10 +34,11 @@ from typing import NamedTuple, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.core.config import (FitConfig, is_source_list,
+from repro.core.config import (FitConfig, is_source_list, resolve_backend,
                                resolve_estep_backend)
 from repro.core.em import (computed_lanes, e_step_stats, fit_gmm,
-                           init_from_means, m_step)
+                           init_from_means, m_step, prepare_rows,
+                           prepared_bytes)
 from repro.core.gmm import GMM
 from repro.core.kmeans import federated_kmeans
 from repro.core.partition import ClientSplit
@@ -239,14 +240,42 @@ class DEMStrategy:
     # -- one round ------------------------------------------------------
 
     def local_step(self, state: DEMState, x, w, idx):
-        """One client's E-step over its own rows -> SufficientStats (the
-        uplink payload; additive, so backends sum it)."""
+        """One client's E-step over its own rows (or their slab,
+        :meth:`prepare_client`) -> SufficientStats (the uplink payload;
+        additive, so backends sum it)."""
         return e_step_stats(state.gmm, x, w, self.backend, self.chunk)
+
+    def _estep(self) -> str:
+        return resolve_estep_backend(self.backend,
+                                     self.covariance_type == "diag")
 
     def lanes_computed(self, d: int) -> int:
         """Feature width a client's E-step computes over."""
-        return computed_lanes(d, resolve_estep_backend(
-            self.backend, self.covariance_type == "diag"))
+        return computed_lanes(d, self._estep())
+
+    def prepare_client(self, x, w):
+        """One client's rows as its E-step reads them in every round: the
+        fused kernel's slab, padded once before the round loop, or the
+        rows themselves (``repro.core.em.prepare_rows``)."""
+        return prepare_rows(x, w, self._estep(), self.chunk)
+
+    def prepared_bytes(self, backend, phase: str):
+        """Device bytes of the clients' rows padded once for the kernels:
+        in the ``"loop"`` phase the E-step's slabs (split clients), in the
+        ``"init"`` phase the fed-kmeans init's Lloyd loops' (resident
+        clients). None where the kernels get raw arrays."""
+        if backend.host:
+            return None
+        c, n, d = backend.num_clients, backend.data.shape[1], backend.dim
+        if phase == "loop" and backend.kind == "split":
+            each = prepared_bytes(n, d, self._estep(), self.chunk)
+        elif phase == "init" and self.init == "fed-kmeans":
+            # federated_kmeans assigns on the "auto" backend
+            each = prepared_bytes(n, d, resolve_backend("auto"), self.chunk,
+                                  weights=False)
+        else:
+            each = None
+        return None if each is None else c * each
 
     def server_combine(self, state: DEMState, stats) -> DEMState:
         gmm = m_step(stats, state.reg_covar)

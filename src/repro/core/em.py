@@ -300,6 +300,9 @@ def e_step_stats(gmm: GMM, x: jax.Array,
     default of 1 is part of the reproducibility contract.
     """
     backend = resolve_estep_backend(estep_backend, gmm.is_diagonal)
+    if _is_slab(x):
+        # rows the loop prepared once for the fused kernel (prepare_rows)
+        return e_step_stats_fused(gmm, x, sample_weight)
     if isinstance(x, DataSource):
         _require_no_weight(sample_weight, "e_step_stats over a DataSource")
         block_fn = (_estep_block_fused if backend == "fused"
@@ -323,15 +326,56 @@ def e_step_stats_fused(gmm: GMM, x: jax.Array,
     """Kernel-backed E-step (diagonal covariance only): the Pallas
     ``estep_stats`` kernel fuses log-pdf -> softmax -> reductions in VMEM.
     Semantically identical to :func:`e_step_stats`; used on TPU where the
-    (N, K) responsibility matrix would otherwise round-trip HBM."""
+    (N, K) responsibility matrix would otherwise round-trip HBM. ``x`` may
+    be the kernel's prepared slab (:func:`prepare_rows`), which carries
+    the weights padded; ``sample_weight`` then gives their sum."""
     from repro.kernels import ops  # local import: kernels are optional
     assert gmm.is_diagonal, "fused E-step kernel supports diagonal covariance"
-    n = x.shape[0]
-    w = jnp.ones(n, x.dtype) if sample_weight is None else sample_weight
-    s0, s1, s2, ll = ops.estep_stats(x, gmm.means, gmm.covs,
-                                     jnp.log(gmm.weights), w,
-                                     interpret=interpret)
-    return SufficientStats(s0, s1, s2, ll, jnp.sum(w))
+    log_w = jnp.log(gmm.weights)
+    if isinstance(x, ops.Slab):
+        w = (jnp.ones(x.n, jnp.float32) if sample_weight is None
+             else sample_weight)
+        stats = ops.estep_stats(x, gmm.means, gmm.covs, log_w,
+                                interpret=interpret)
+    else:
+        w = (jnp.ones(x.shape[0], x.dtype) if sample_weight is None
+             else sample_weight)
+        stats = ops.estep_stats(x, gmm.means, gmm.covs, log_w, w,
+                                interpret=interpret)
+    return SufficientStats(*stats, jnp.sum(w))
+
+
+def _is_slab(x) -> bool:
+    if isinstance(x, (jax.Array, DataSource)):
+        return False
+    from repro.kernels import ops  # local import: kernels are optional
+    return isinstance(x, ops.Slab)
+
+
+def prepare_rows(x: jax.Array, sample_weight: Optional[jax.Array],
+                 backend: str, chunk_size: Optional[int]):
+    """What a loop over resident rows hands its fused-kernel calls: the
+    kernels' padded slab of ``x`` (``repro.kernels.ops.prepare``), built
+    once where the resolved ``backend`` is fused and the rows run as one
+    batch; ``x`` itself anywhere else (reference backend, chunked rows),
+    where each call pads its own block. Build it before ``lax.while_loop``
+    and let the body close over it: a carried slab would be selected
+    whole on every iteration of a vmapped loop."""
+    if backend != "fused" or chunk_size is not None:
+        return x
+    from repro.kernels import ops  # local import: kernels are optional
+    return ops.prepare(x, sample_weight)
+
+
+def prepared_bytes(n: int, d: int, backend: str, chunk_size: Optional[int],
+                   weights: bool = True) -> Optional[int]:
+    """Device bytes of the slab :func:`prepare_rows` builds for ``(n, d)``
+    rows (``weights``: with the E-step's weight column; the k-means
+    assignment reads the rows alone); None where it builds none."""
+    if backend != "fused" or chunk_size is not None:
+        return None
+    from repro.kernels import ops  # local import: kernels are optional
+    return ops.slab_bytes(n, d, weights)
 
 
 def computed_lanes(d: int, backend: str) -> int:
@@ -648,19 +692,24 @@ def _moments_block(xb: jax.Array, wb: jax.Array):
 def _em_loop(gmm0: GMM, x: jax.Array, w: jax.Array, tol: float,
              reg_covar: float, max_iter: int, estep_backend: str = "auto",
              chunk_size: Optional[int] = None):
+    # the fused kernel's slab, padded once for every iteration
+    rows = prepare_rows(
+        x, w, resolve_estep_backend(estep_backend, gmm0.is_diagonal),
+        chunk_size)
+
     def cond(state):
         _, prev_ll, ll, it = state
         return jnp.logical_and(it < max_iter, jnp.abs(ll - prev_ll) > tol)
 
     def body(state):
         gmm, _, ll, it = state
-        new_gmm, avg_ll = em_step(gmm, x, w, reg_covar, estep_backend,
+        new_gmm, avg_ll = em_step(gmm, rows, w, reg_covar, estep_backend,
                                   chunk_size)
         return new_gmm, ll, avg_ll, it + 1
 
     neg_inf = jnp.array(-jnp.inf, x.dtype)
     # Bootstrap: one step to get an initial loglik.
-    gmm1, ll0 = em_step(gmm0, x, w, reg_covar, estep_backend, chunk_size)
+    gmm1, ll0 = em_step(gmm0, rows, w, reg_covar, estep_backend, chunk_size)
     state = (gmm1, neg_inf, ll0, jnp.array(1))
     gmm, prev_ll, ll, it = jax.lax.while_loop(cond, body, state)
     converged = jnp.abs(ll - prev_ll) <= tol
@@ -758,6 +807,28 @@ def fit_gmm_cfg(key: jax.Array, x, k: int, config: FitConfig,
         init_gmm, x, w, jnp.asarray(tol, x.dtype), config.reg_covar,
         max_iter, config.backend, cs)
     return EMResult(gmm, ll, it, converged)
+
+
+def fit_prepared_bytes(n: int, d: int, config: FitConfig) -> Optional[int]:
+    """Device bytes of the kernel slabs one resident :func:`fit_gmm_cfg`
+    of ``(n, d)`` rows builds once (:func:`prepare_rows`): the EM loop's,
+    with its weight column, and those of the k-means init, whose Lloyd
+    loops assign on the "auto" backend: the rows (one copy with the EM
+    loop's, as the compiler merges equal pads) and, past
+    ``repro.core.kmeans.SEED_ROWS`` rows, the restarts' row subsample,
+    which runs as one batch whatever the chunk size. None where every
+    kernel call gets raw rows."""
+    from repro.core.kmeans import SEED_ROWS  # kmeans builds on this module
+    chunk = config.resolve_chunk(source=False)
+    assign = resolve_backend("auto")
+    rows = prepared_bytes(n, d, config.resolved_estep(), chunk)
+    if rows is None:
+        rows = prepared_bytes(n, d, assign, chunk, weights=False)
+    seed = (prepared_bytes(SEED_ROWS, d, assign, None, weights=False)
+            if n > SEED_ROWS else None)
+    if rows is None and seed is None:
+        return None
+    return (rows or 0) + (seed or 0)
 
 
 def fit_gmm(key: jax.Array, x: jax.Array, k: int,

@@ -29,7 +29,7 @@ from jax.profiler import TraceAnnotation
 
 from repro.core.config import FitConfig, is_source_list
 from repro.core.em import (EMResult, computed_lanes, fit_gmm_bic_cfg,
-                           fit_gmm_cfg)
+                           fit_gmm_cfg, fit_prepared_bytes)
 from repro.core.gmm import GMM, merge_gmms
 from repro.core.partition import ClientSplit
 from repro.data.sources import DataSource, SyntheticGMMSource
@@ -294,10 +294,15 @@ class FedGenStrategy:
             raise TypeError(
                 "FedGenStrategy runs ClientSplit or source-list clients; "
                 "the mesh variant is repro.distributed.fedgen_sharded")
-        counters = slab_counters(backend, computed_lanes(
-            int(backend.dim), self.config.resolved_estep()))
+        d = int(backend.dim)
+        lanes = computed_lanes(d, self.config.resolved_estep())
         sizes = backend.sizes
         if backend.kind == "split" and self.k_clients is not None:
+            each = fit_prepared_bytes(int(backend.data.shape[1]), d,
+                                      self.config)
+            counters = slab_counters(
+                backend, lanes, prepared_bytes=None if each is None
+                else backend.num_clients * each)
             with TraceAnnotation("repro.fedgen.local", **counters):
                 stacked, lls, iters = train_locals_cfg(
                     state["k_local"], backend.data, backend.mask,
@@ -311,6 +316,7 @@ class FedGenStrategy:
                     for i, g in enumerate(local_gmms)]
         else:
             # each client is fitted on its own rows, off the padded slab
+            counters = slab_counters(backend, lanes)
             counters.pop("rows_computed", None)
             with TraceAnnotation("repro.fedgen.local", **counters):
                 if backend.kind == "sources":

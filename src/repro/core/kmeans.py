@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from repro.core.config import (FitConfig, is_source_list,
                                require_array_weights, resolve_backend,
                                resolve_source_chunk)
-from repro.core.em import (SufficientStats, reduce_rows,
+from repro.core.em import (SufficientStats, prepare_rows, reduce_rows,
                            streaming_map_reduce, streaming_reduce)
 from repro.core.gmm import MATMUL_PRECISION
 from repro.data.sources import DataSource, prefetch_blocks
@@ -73,7 +73,8 @@ def _sq_dists(x: jax.Array, centers: jax.Array) -> jax.Array:
 def _assign_block(xb: jax.Array, centers: jax.Array,
                   backend: str) -> tuple[jax.Array, jax.Array]:
     """Nearest-center assignment of one row block -> ((B,) int32, (B,) d2).
-    ``fused`` routes through the Pallas ``kmeans_assign`` kernel, reference
+    ``fused`` routes through the Pallas ``kmeans_assign`` kernel (``xb``
+    may be its prepared slab, ``repro.core.em.prepare_rows``), reference
     through the matmul identity; both share the §3 contraction."""
     if backend == "fused":
         from repro.kernels import ops  # local import: kernels are optional
@@ -95,11 +96,13 @@ def _labels_onehot(idx: jax.Array, k: int, wb: jax.Array,
 
 
 def _sweep_block(xb: jax.Array, wb: jax.Array, centers: jax.Array,
-                 backend: str):
+                 backend: str, ab=None):
     """Weighted Lloyd-sweep sufficient statistics of one block:
-    (counts (K,), sums (K, d), inertia ())."""
+    (counts (K,), sums (K, d), inertia ()). The assignment reads ``ab``,
+    the block's prepared slab, where the caller has one; the sums read
+    the rows themselves."""
     k = centers.shape[0]
-    idx, d2 = _assign_block(xb, centers, backend)
+    idx, d2 = _assign_block(xb if ab is None else ab, centers, backend)
     oh = _labels_onehot(idx, k, wb, xb.dtype)
     return (jnp.sum(oh, axis=0),
             jnp.matmul(oh.T, xb, precision=MATMUL_PRECISION),
@@ -176,9 +179,12 @@ def kmeans(key: jax.Array, x: jax.Array, k: int,
         centers0 = init_centers
     else:
         centers0 = _seed_centers(key, x, k, w, seed_rows)
+    # what the full-batch assignment reads: the kernel's slab, padded once
+    # for every sweep, or x itself
+    rows = prepare_rows(x, None, backend, chunk_size)
 
-    def block_stats(xb, wb, centers):
-        idx, d2 = _assign_block(xb, centers, backend)
+    def block_stats(xb, wb, centers, ab):
+        idx, d2 = _assign_block(ab, centers, backend)
         oh = _labels_onehot(idx, k, wb, xb.dtype)
         sums = jnp.matmul(oh.T, xb, precision=MATMUL_PRECISION)
         return (jnp.sum(oh, axis=0), sums, jnp.sum(d2 * wb)), idx
@@ -186,17 +192,18 @@ def kmeans(key: jax.Array, x: jax.Array, k: int,
     def sweep(centers):
         """One assignment pass -> ((counts, sums, inertia), assignments)."""
         if chunk_size is None:
-            return block_stats(x, w, centers)
+            return block_stats(x, w, centers, rows)
         return streaming_map_reduce(
-            lambda xb, wb: block_stats(xb, wb, centers), (x, w), chunk_size)
+            lambda xb, wb: block_stats(xb, wb, centers, xb), (x, w),
+            chunk_size)
 
-    def update_block(xb, wb, centers):
+    def update_block(xb, wb, centers, ab):
         """counts/sums only — the Lloyd loop never reads inertia, so the
         assignment reduces to ``argmax(x·c - ||c||²/2)``: one matmul per
         block, no per-row ``x²`` term or min-distance pass (both are
         assignment-invariant constants per row)."""
         if backend == "fused":
-            idx, _ = _assign_block(xb, centers, backend)
+            idx, _ = _assign_block(ab, centers, backend)
         else:
             xc = jnp.matmul(xb, centers.T, precision=MATMUL_PRECISION)
             score = xc - 0.5 * jnp.sum(centers * centers, axis=1)[None, :]
@@ -207,8 +214,11 @@ def kmeans(key: jax.Array, x: jax.Array, k: int,
 
     def sweep_stats(centers):
         """Reduce-only sweep for the Lloyd loop (assignments not collected)."""
-        return reduce_rows(lambda xb, wb: update_block(xb, wb, centers),
-                           (x, w), chunk_size)
+        if chunk_size is None:
+            return update_block(x, w, centers, rows)
+        return streaming_reduce(
+            lambda xb, wb: update_block(xb, wb, centers, xb), (x, w),
+            chunk_size)
 
     def cond(state):
         _, it, shift = state
@@ -277,9 +287,13 @@ def kmeans_multi(key: jax.Array, x: jax.Array, k: int,
     else:
         xs, ws, pilot_chunk = x, w, chunk_size
     keys = jax.random.split(key, n_init)
+    # one slab of the pilot's rows for every restart and sweep
+    pilot_rows = prepare_rows(xs, None, backend, pilot_chunk)
 
     def sweep_stats(centers):
-        return reduce_rows(
+        if pilot_chunk is None:
+            return _sweep_block(xs, ws, centers, backend, pilot_rows)
+        return streaming_reduce(
             lambda xb, wb: _sweep_block(xb, wb, centers, backend),
             (xs, ws), pilot_chunk)
 

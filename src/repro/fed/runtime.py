@@ -87,6 +87,14 @@ class FederationStrategy(Protocol):
     - ``lanes_computed(d) -> int`` (optional) — the feature width
       ``local_step`` computes over (d, or d padded by a kernel), for the
       round loop's profiler counters; left out of them when absent.
+    - ``prepare_client(x, w)`` (optional) — one resident client's rows as
+      ``local_step`` reads them, built once before the jitted round loop
+      (the fused kernel's padded slab, or ``x`` itself); absent, or on
+      sharded and source clients, ``local_step`` gets the rows.
+    - ``prepared_bytes(backend, phase) -> int | None`` (optional) — the
+      device bytes of padded kernel operands the ``"init"`` or
+      ``"loop"`` phase builds once, for that phase's profiler span; left
+      out of it when absent or None.
     - ``round_payload(backend, state) -> RoundPayload`` — what one round
       moves; the driver multiplies by the realized round count.
     - ``finalize(state, n_rounds, converged, comm) -> result``.
@@ -156,22 +164,34 @@ def _wrap_step(local_step, state, transform, tparams, tkey, members):
 
 @jax.tree_util.register_pytree_node_class
 class SplitClients:
-    """Resident padded clients: ``data (C, N, d)``, ``mask (C, N)``."""
+    """Resident padded clients: ``data (C, N, d)``, ``mask (C, N)``.
+    Each client's step reads its rows of ``data``, or of ``rows``, the
+    strategy's ``prepare_client`` of them stacked over clients, once
+    :meth:`prepared` has built it."""
 
     kind = "split"
     host = False
 
-    def __init__(self, data: jax.Array, mask: jax.Array, split=None):
+    def __init__(self, data: jax.Array, mask: jax.Array, split=None,
+                 rows=None):
         self.data = data
         self.mask = mask
         self.split = split  # the original ClientSplit (host metadata)
+        self.rows = rows
 
     def tree_flatten(self):
-        return (self.data, self.mask), None
+        return (self.data, self.mask, self.rows), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(*children)
+        data, mask, rows = children
+        return cls(data, mask, rows=rows)
+
+    def prepared(self, prepare_client) -> "SplitClients":
+        """These clients with ``prepare_client(x, w)`` of every client's
+        rows as the rows its step reads, built once."""
+        return SplitClients(self.data, self.mask, self.split,
+                            jax.vmap(prepare_client)(self.data, self.mask))
 
     @property
     def num_clients(self) -> int:
@@ -198,8 +218,9 @@ class SplitClients:
         members = jnp.arange(c) if cohort is None else cohort
         step = _wrap_step(local_step, state, transform, tparams, tkey,
                           members)
+        rows = self.data if self.rows is None else self.rows
         if cohort is None:
-            per = jax.vmap(step)(self.data, self.mask, members)
+            per = jax.vmap(step)(rows, self.mask, members)
             if weights is not None:
                 per = jax.tree.map(
                     lambda s: s * _weight_bcast(weights, s), per)
@@ -209,7 +230,7 @@ class SplitClients:
         # membership changes) and m is static (one compiled shape for
         # all rounds).
         per = jax.vmap(step)(
-            jnp.take(self.data, cohort, axis=0),
+            jax.tree.map(lambda r: jnp.take(r, cohort, axis=0), rows),
             jnp.take(self.mask, cohort, axis=0), cohort)
         if weights is not None:
             per = jax.tree.map(lambda s: s * _weight_bcast(weights, s), per)
@@ -405,23 +426,28 @@ class ShardedClients:
 
 
 def slab_counters(backend, lanes_computed: Optional[int] = None,
-                  cohort_size: Optional[int] = None) -> dict:
+                  cohort_size: Optional[int] = None,
+                  prepared_bytes: Optional[int] = None) -> dict:
     """Counters of the client data one round computes over, for a
     profiler span: ``clients`` (the cohort's size when one is sampled);
     ``rows``, their real rows; ``rows_computed``, the rows of the padded
     ``(clients, N, d)`` slab; ``lanes``, the feature width d;
     ``lanes_computed``, the width the clients' op computes over, as the
-    layer that picks its backend gives it (left out where none does).
-    Taken from shapes and host arrays alone: a count that lives on the
-    device is left out, never fetched (that would wait for the device).
-    So are a sampled cohort's rows, which change from round to round, and
-    ``rows_computed`` for source clients, whose block padding the engine
-    owns."""
+    layer that picks its backend gives it (left out where none does);
+    ``prepared_bytes``, the device bytes of padded kernel operands built
+    once for the phase, as that layer gives it (left out where the
+    kernels get raw arrays). Taken from shapes and host arrays alone: a
+    count that lives on the device is left out, never fetched (that would
+    wait for the device). So are a sampled cohort's rows, which change
+    from round to round, and ``rows_computed`` for source clients, whose
+    block padding the engine owns."""
     d = int(backend.dim)
     c = int(backend.num_clients if cohort_size is None else cohort_size)
     out = {"clients": c, "lanes": d}
     if lanes_computed is not None:
         out["lanes_computed"] = int(lanes_computed)
+    if prepared_bytes is not None:
+        out["prepared_bytes"] = int(prepared_bytes)
     if backend.kind != "sources":
         out["rows_computed"] = c * int(backend.data.shape[1])
     if cohort_size is None:
@@ -520,7 +546,12 @@ def _iterate_jit(strategy, backend, state0, max_rounds: int,
     (tol, reg_covar, the transform's epsilon/delta) ride in ``state0`` /
     ``tparams`` as traced leaves and the sampler/straggler/transform
     PRNG keys (``skey``/``dkey``/``tkey``) are traced, so sweeping knobs
-    or reseeding does not recompile."""
+    or reseeding does not recompile. A strategy's ``prepare_client``
+    runs on split clients once, before the loop, whose body closes over
+    the result (never a carry element)."""
+    prepare = getattr(strategy, "prepare_client", None)
+    if prepare is not None and backend.kind == "split":
+        backend = backend.prepared(prepare)
 
     def one_round(state, rnd):
         cohort, weights = _cohort_and_weights(sampler, stragglers, backend,
@@ -657,17 +688,27 @@ def run_rounds(strategy, clients, *, key: Optional[jax.Array] = None,
                 "straggler handling needs a round structure; one-shot "
                 "strategies take no straggler policy")
         dkey = jax.random.key(int(getattr(stragglers, "seed", 0)))
+    # the phases' bytes of padded kernel operands built once, as the
+    # strategy, which picks the clients' backend, gives them
+    prepared = getattr(strategy, "prepared_bytes", None)
+
+    def prepared_in(phase):
+        return None if prepared is None else prepared(backend, phase)
+
     if state0 is None:
-        with TraceAnnotation("repro.rounds.init"):
+        init_bytes = prepared_in("init")
+        with TraceAnnotation("repro.rounds.init", **(
+                {} if init_bytes is None
+                else {"prepared_bytes": init_bytes})):
             state0 = strategy.init_state(key, backend)
 
     cohort_size = None if sampler is None else sampler.cohort_size
-    # the loop spans' counters; the strategy, which picks the clients'
-    # backend, gives the width they compute over
+    # the loop spans' counters; the strategy gives the width the clients
+    # compute over
     lanes = getattr(strategy, "lanes_computed", None)
     slab = None if one_shot else slab_counters(
         backend, None if lanes is None else lanes(int(backend.dim)),
-        cohort_size)
+        cohort_size, prepared_in("loop"))
     if one_shot:
         if transform is not None:
             state = strategy.run_once(state0, backend,

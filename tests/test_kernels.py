@@ -86,6 +86,33 @@ class TestEstepStats:
         s0, *_ = ops.estep_stats(x, mu, var, lw, None, interpret=True)
         np.testing.assert_allclose(float(jnp.sum(s0)), 200.0, rtol=1e-4)
 
+    @pytest.mark.parametrize("n,d,k", SHAPES)
+    def test_prepared_slab_is_bit_identical(self, n, d, k):
+        """A slab prepared once gives the kernel the bytes a per-call pad
+        gives it: the same statistics, bit for bit."""
+        rng = np.random.default_rng(n * 5 + d + k)
+        x, mu, var, lw = make_inputs(rng, n, d, k)
+        w = jnp.asarray(rng.uniform(0, 1, n), jnp.float32)
+        per_call = ops.estep_stats(x, mu, var, lw, w, interpret=True)
+        slab = ops.estep_stats(ops.prepare(x, w), mu, var, lw,
+                               interpret=True)
+        for a, b in zip(per_call, slab):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def test_slab_carries_its_weights(self):
+        rng = np.random.default_rng(5)
+        x, mu, var, lw = make_inputs(rng, 100, 8, 4)
+        slab = ops.prepare(x)
+        assert slab.x.shape == (512, 128) and slab.w.shape == (512, 1)
+        assert (slab.n, slab.d) == (100, 8)
+        np.testing.assert_array_equal(np.asarray(slab.w[:, 0]),
+                                      np.r_[np.ones(100), np.zeros(412)])
+        with pytest.raises(ValueError, match="carries its weights"):
+            ops.estep_stats(slab, mu, var, lw, jnp.ones(100),
+                            interpret=True)
+        assert ops.slab_bytes(100, 8) == 512 * 256 * 4
+        assert ops.slab_bytes(100, 8, weights=False) == 512 * 128 * 4
+
     def test_multi_block_accumulation(self):
         """Accumulation across sequential grid steps must equal single block."""
         rng = np.random.default_rng(4)
@@ -107,6 +134,15 @@ class TestKmeansAssign:
         assert bool(jnp.all(ia == ie))
         np.testing.assert_allclose(np.asarray(da), np.asarray(de), rtol=1e-4,
                                    atol=1e-4)
+
+    @pytest.mark.parametrize("n,d,k", SHAPES)
+    def test_prepared_slab_is_bit_identical(self, n, d, k):
+        rng = np.random.default_rng(n * 7 + d + k)
+        x, mu, _, _ = make_inputs(rng, n, d, k)
+        per_call = ops.kmeans_assign(x, mu, interpret=True)
+        slab = ops.kmeans_assign(ops.prepare(x), mu, interpret=True)
+        for a, b in zip(per_call, slab):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @settings(max_examples=15, deadline=None)

@@ -20,10 +20,11 @@ from repro.core.dem import DEMStrategy
 from repro.core.gmm import GMM
 from repro.core.partition import partition
 from repro.data.sources import ArraySource
-from repro.core.em import computed_lanes
+from repro.core.em import computed_lanes, fit_prepared_bytes
+from repro.core.kmeans import SEED_ROWS
 from repro.fed.runtime import _iterate_jit, make_backend, slab_counters
 from repro.fed.strategies import FedKMeansStrategy
-from repro.kernels.ops import LANES, padded_lanes
+from repro.kernels.ops import LANES, padded_lanes, slab_bytes
 from repro.serve import ScoreConfig, ScoreRequest, ScoringEngine
 from conftest import planted_gmm_data
 
@@ -178,6 +179,52 @@ def test_dem_reference_backend_counts_its_own_width(tmp_path, split):
     _, spans = traced(tmp_path, lambda: dem.run(split,
                                                  key=jax.random.key(0)))
     assert counters(spans, "repro.rounds.loop") == [_slab(split)]
+
+
+@pytest.mark.parametrize("backend", ["fused", "reference"])
+def test_prepared_bytes_count_the_client_slab(tmp_path, split, backend):
+    """The fused E-step's loops pad every client's rows once: the local
+    fits' span and the round loop's count that slab's bytes. The
+    reference path pads nothing and counts nothing."""
+    config = FitConfig(max_iter=3, backend=backend)
+    fed = FedGenGMM(k_clients=K, k_global=K, h=20, config=config)
+    dem = DEM(K, config=config)
+    _, spans = traced(tmp_path, lambda: (
+        fed.run(split, key=jax.random.key(0)),
+        dem.run(split, key=jax.random.key(0))))
+    want = ([CLIENTS * slab_bytes(split.data.shape[1], D)]
+            if backend == "fused" else [])
+    for name in ("repro.fedgen.local", "repro.rounds.loop"):
+        assert [c["prepared_bytes"] for c in counters(spans, name)
+                if "prepared_bytes" in c] == want, name
+    # the fed-kmeans init assigns on "auto", the reference path on a CPU
+    assert all("prepared_bytes" not in c
+               for c in counters(spans, "repro.rounds.init"))
+
+
+def test_prepared_bytes_on_the_chip(monkeypatch, split):
+    """Where "auto" picks the kernels: the fed-kmeans init pads the
+    clients' rows (no weight column), the round loop the E-step's slab; a
+    local fit also pads its k-means seeding subsample once it has more
+    rows. Chunked rows, full covariance and source clients pad per call."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    backend = make_backend(split)
+    n = split.data.shape[1]
+    dem = DEMStrategy(k=K)
+    assert dem.prepared_bytes(backend, "init") == \
+        CLIENTS * slab_bytes(n, D, weights=False)
+    assert dem.prepared_bytes(backend, "loop") == CLIENTS * slab_bytes(n, D)
+    assert DEMStrategy(k=K, chunk=64).prepared_bytes(backend, "loop") is None
+    assert DEMStrategy(k=K, covariance_type="full").prepared_bytes(
+        backend, "loop") is None
+    sources = make_backend([ArraySource(np.asarray(split.data[0]))])
+    assert dem.prepared_bytes(sources, "init") is None
+    big, seed = SEED_ROWS + 1, slab_bytes(SEED_ROWS, D, weights=False)
+    assert fit_prepared_bytes(big, D, FitConfig()) == \
+        slab_bytes(big, D) + seed
+    assert fit_prepared_bytes(big, D, FitConfig(chunk_size=64)) == seed
+    assert fit_prepared_bytes(n, D, FitConfig(covariance_type="full")) == \
+        slab_bytes(n, D, weights=False)
 
 
 def test_fedgen_spans_nest_and_count(tmp_path, split):

@@ -15,10 +15,11 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as hst
 
-from repro.api import GMMEstimator
+from repro.api import DEM, FedEM, FitConfig, GMMEstimator
 from repro.core.em import (e_step_stats, e_step_stats_chunked, fit_gmm,
                            init_from_kmeans, resolve_estep_backend)
-from repro.core.fedgen import fedgengmm, train_locals_bic
+from repro.core.fedgen import fedgengmm, train_locals, train_locals_bic
+from repro.core.kmeans import kmeans, kmeans_multi
 from repro.core.gmm import GMM
 from repro.core.partition import partition
 
@@ -159,6 +160,45 @@ class TestEndToEndParity:
             < 1e-4
         np.testing.assert_allclose(np.asarray(ref.gmm.means),
                                    np.asarray(fused.gmm.means),
+                                   rtol=1e-3, atol=1e-3)
+
+    @pytest.mark.parametrize("run", ["train_locals", "dem", "fedem_cohort",
+                                     "kmeans", "kmeans_multi"])
+    def test_prepared_loops_match_reference(self, run):
+        """The loops that pad their rows once for the kernels (the local
+        fits, the round loop, a sampled cohort's rounds, the Lloyd
+        loops) land where the reference backend lands."""
+        x, y, _ = planted_gmm_data(np.random.default_rng(11), n=900, d=3,
+                                   k=3, spread=6.0, std=0.5,
+                                   min_sep_sigma=8.0)
+        split = partition(np.random.default_rng(5), x, y, 3, "dirichlet",
+                          5.0)
+        key = jax.random.key(0)
+
+        def fit(backend):
+            if run == "train_locals":
+                gmm, ll, _ = train_locals(key, jnp.asarray(split.data),
+                                          jnp.asarray(split.mask), 3,
+                                          estep_backend=backend)
+                return gmm.means, ll
+            if run == "dem":
+                res = DEM(3, config=FitConfig(backend=backend)).run(
+                    split, key=key)
+                return res.global_gmm.means, res.log_likelihood
+            if run == "fedem_cohort":
+                res = FedEM(3, participation=2 / 3, config=FitConfig(
+                    backend=backend, max_iter=8)).run(split, key=key)
+                return res.global_gmm.means, res.log_likelihood
+            fn = kmeans if run == "kmeans" else kmeans_multi
+            res = fn(key, jnp.asarray(x), 3, max_iter=20,
+                     assign_backend=backend, seed_rows=512)
+            return res.centers, res.inertia / x.shape[0]
+
+        ref_means, ref_ll = fit("reference")
+        means, ll = fit("fused")
+        np.testing.assert_allclose(np.asarray(ll), np.asarray(ref_ll),
+                                   atol=1e-4)
+        np.testing.assert_allclose(np.asarray(means), np.asarray(ref_means),
                                    rtol=1e-3, atol=1e-3)
 
     @pytest.mark.parametrize("chunk_size", [128, 500, 4096])

@@ -7,17 +7,26 @@ catches what interpret mode cannot (tile alignment, VMEM limits, a kernel
 the compiler refuses) at no chip time. Each compiled program must hold the
 Mosaic kernel itself (``tpu_custom_call``), not an interpret-mode loop.
 
+The fit loops are compiled whole as well: their kernel operands must be
+padded once, before ``lax.while_loop``, never inside a loop body.
+
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 every test file.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.config import FitConfig
+from repro.core.dem import DEMStrategy
+from repro.core.fedgen import _train_locals_jit
+from repro.core.gmm import GMM
+from repro.fed.runtime import SplitClients, _iterate_jit
 from repro.kernels import ops
 
 F32 = jnp.float32
@@ -84,3 +93,124 @@ def test_vmapped_estep_stats_compiles(one_chip):
     _assert_kernel(clients, _spec(one_chip, c, n, d), _spec(one_chip, c, n),
                    _spec(one_chip, k, d), _spec(one_chip, k, d),
                    _spec(one_chip, k))
+
+
+# -- the fit loops: kernel operands padded once, outside every loop body ----
+
+C, N, K = 2, 4000, 10   # N is no multiple of the kernels' 512-row block
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """The program as it resolves on the chip: "auto" backends pick the
+    kernels and the wrappers compile them for Mosaic, not interpret mode
+    (both ask ``jax.default_backend()``). Traces made under the CPU's
+    answer are dropped, before and after."""
+    jax.clear_caches()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    yield
+    monkeypatch.undo()
+    jax.clear_caches()
+
+
+_CALLS = re.compile(r"(?:body|condition|calls|to_apply|true_computation|"
+                    r"false_computation)=%([\w.\-]+)"
+                    r"|branch_computations=\{([^}]*)\}")
+_PAD_OR_COPY = re.compile(r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* "
+                          r"(pad|copy)\(")
+
+
+def loop_pads(hlo: str) -> list:
+    """(op, shape) of every ``pad`` and ``copy`` in a ``while`` body of the
+    compiled program, or in a computation such a body calls."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) .*\{$", line)
+        if name is None and head:
+            name, comps[head.group(1)] = head.group(1), []
+        elif name is not None and line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    todo = [m.group(1) for lines in comps.values() for line in lines
+            for m in [re.search(r"\bwhile\(.*\bbody=%([\w.\-]+)", line)]
+            if m]
+    seen = set()
+    while todo:
+        comp = todo.pop()
+        if comp in seen or comp not in comps:
+            continue
+        seen.add(comp)
+        for line in comps[comp]:
+            for one, many in _CALLS.findall(line):
+                todo.extend([one] if one else
+                            [c.strip().lstrip("%") for c in many.split(",")])
+    found = []
+    for comp in seen:
+        for line in comps[comp]:
+            m = _PAD_OR_COPY.match(line)
+            if m:
+                shape = tuple(int(v) for v in m.group(1).split(",") if v)
+                found.append((m.group(2), shape))
+    return found
+
+
+def _assert_slab_built_once(hlo: str, n: int, d: int):
+    """The kernel runs, and no loop rebuilds the client slab: neither the
+    padded rows ``(n_pad, 128·)`` nor the ``(n, 1)`` weight column, before
+    or after its padding."""
+    assert "tpu_custom_call" in hlo
+    n_pad = -(-n // ops.BLOCK_N) * ops.BLOCK_N
+    slab = {(n_pad, ops.padded_lanes(d)), (n, 1), (n_pad, 1)}
+    in_loops = [(op, shape) for op, shape in loop_pads(hlo)
+                if shape[-2:] in slab]
+    assert in_loops == [], in_loops
+
+
+def test_loop_pads_reads_while_bodies():
+    hlo = """%body (p: f32[4]) -> f32[4] {
+  %a = f32[8,128]{1,0} pad(%p, %z), padding=0_4x0_0
+  %b = f32[4]{0} fusion(%p), kind=kLoop, calls=%fused
+}
+
+%fused (q: f32[4]) -> f32[4] {
+  ROOT %c = f32[512,1]{1,0:T(8,128)} copy(%q)
+}
+
+ENTRY %main (x: f32[4]) -> f32[4] {
+  %d = f32[9,9]{1,0} pad(%x, %z), padding=0_5x0_5
+  ROOT %w = f32[4] while(%x), condition=%cond, body=%body
+}
+"""
+    assert sorted(loop_pads(hlo)) == [("copy", (512, 1)), ("pad", (8, 128))]
+
+
+def test_fedgen_local_fits_pad_once(one_chip, on_tpu):
+    """FedGenGMM's vmapped local fits: the k-means init's Lloyd loops and
+    the EM loop read slabs built before they iterate."""
+    d = 84
+    key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                               sharding=one_chip)
+    config = FitConfig(backend="fused").resolved_for("em").replace(
+        seed=0, init="auto")
+    hlo = _train_locals_jit.lower(
+        key, _spec(one_chip, C, N, d), _spec(one_chip, C, N), k=K,
+        config=config).compile().as_text()
+    _assert_slab_built_once(hlo, N, d)
+
+
+def test_dem_round_loop_pads_once(one_chip, on_tpu):
+    """DEM's resident round loop: every round's E-step reads the clients'
+    slabs, prepared before the loop."""
+    d = 38
+    strategy = DEMStrategy(k=K)
+    state0 = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip,
+                                       weak_type=s.weak_type),
+        jax.eval_shape(lambda: strategy.state_from_gmm(
+            GMM(jnp.full((K,), 1.0 / K), jnp.zeros((K, d)),
+                jnp.ones((K, d))), dtype=F32)))
+    backend = SplitClients(_spec(one_chip, C, N, d), _spec(one_chip, C, N))
+    hlo = _iterate_jit.lower(strategy, backend, state0,
+                             200).compile().as_text()
+    _assert_slab_built_once(hlo, N, d)
