@@ -20,8 +20,8 @@ generated from ``--seed``. In one process, in order:
    ``tpu_custom_call``.
 
 With ``--chips 4`` it runs only the sharded path instead:
-``dem_sharded`` and ``fedgen_sharded`` on a 4-device mesh (5 clients per
-chip) beside the same strategies on unsharded clients.
+``repro.api.DEM(k, mesh=...)`` and ``fedgen_sharded`` on a 4-device mesh
+(5 clients per chip) beside the same strategies on unsharded clients.
 
 The last line of standard output is ``{"ok": true, "device": {...}}``.
 Any failed check exits non-zero without it.
@@ -316,11 +316,9 @@ def _max_abs_diff(a, b) -> float:
 
 def run_sharded(args) -> None:
     import jax
-    import jax.numpy as jnp
     from repro.api import DEM, FedGenGMM, FitConfig
-    from repro.core.kmeans import federated_kmeans
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.distributed import dem_sharded, fedgen_sharded
+    from repro.distributed import fedgen_sharded
 
     ds, split = make_wadi(args.rows, args.seed)
     k = ds.k_global
@@ -381,12 +379,11 @@ def run_sharded(args) -> None:
 
     dem = DEM(k, config=dem_cfg).run(split, key=key)
     jax.block_until_ready(dem.global_gmm)
-    # the centers DEM's fed-kmeans init draws from the same key
-    centers = federated_kmeans(jax.random.split(key)[0],
-                               jnp.asarray(split.data), k,
-                               client_weights=jnp.asarray(split.mask))
-    g_sh, rounds = dem_sharded(mesh, key, data, mask, k, centers,
-                               config=dem_cfg)
+    # the same facade on the mesh: its fed-kmeans init runs on the shards
+    # with the single-process key schedule
+    placed = split._replace(data=data, mask=mask)
+    dem_sh = DEM(k, config=dem_cfg, mesh=mesh).run(placed, key=key)
+    g_sh, rounds = dem_sh.global_gmm, dem_sh.n_rounds
     jax.block_until_ready(g_sh)
     g_un = dem.global_gmm
     print(f"dem: {int(dem.n_rounds)} / {int(rounds)} rounds; weights "
@@ -402,7 +399,7 @@ def run_sharded(args) -> None:
         a = np.asarray(getattr(g_un, f))
         b = np.asarray(getattr(g_sh, f))
         soft_check(bool(np.allclose(a, b, rtol=1e-3, atol=1e-4)),
-                   f"dem_sharded {f} match unsharded DEM")
+                   f"DEM on the mesh: {f} match unsharded DEM")
     if failures:
         raise SmokeFailure("; ".join(failures))
 

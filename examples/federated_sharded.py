@@ -15,10 +15,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.api import FitConfig, GMMEstimator
+from repro.api import DEM, FitConfig, GMMEstimator
 from repro.core import partition
-from repro.core.dem import fed_kmeans_centers
-from repro.distributed import dem_sharded, fedgen_sharded
+from repro.distributed import fedgen_sharded
 
 mesh = jax.make_mesh((len(jax.devices()),), ("data",))
 print(f"mesh: {mesh}")
@@ -37,10 +36,11 @@ res = fedgen_sharded(mesh, jax.random.key(0), data, mask, k=4, k_global=4,
                      h=80, config=cfg)
 print(f"FedGenGMM (1 all-gather):   ll={float(res.global_gmm.score(xj)):.4f}")
 
-centers = fed_kmeans_centers(jax.random.key(1), split, 4)
-gmm, rounds = dem_sharded(mesh, jax.random.key(2), data, mask, 4, centers,
-                          config=cfg.replace(max_iter=100))
-print(f"DEM ({int(rounds)} psum rounds):       ll={float(gmm.score(xj)):.4f}")
+# DEM through the facade: the fed-kmeans init and every round on the shards
+dem = DEM(4, mesh=mesh, config=cfg.replace(max_iter=100)).run(
+    split, key=jax.random.key(2))
+print(f"DEM ({int(dem.n_rounds)} psum rounds):       "
+      f"ll={float(dem.global_gmm.score(xj)):.4f}")
 
 bench = GMMEstimator(4, seed=3).fit(xj)
 print(f"non-federated benchmark:    ll={float(bench.score(xj)):.4f}")

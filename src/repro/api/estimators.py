@@ -405,13 +405,23 @@ class DEM:
     separated centers for source clients; "pilot" uploads raw rows and
     needs resident data). ``FitConfig.max_iter`` bounds the communication
     rounds. Returns a :class:`repro.core.dem.DEMResult`.
+
+    ``mesh`` (a ``jax.sharding.Mesh`` with a ``"data"`` axis) shards a
+    split's clients over the devices, ``C / shards`` each: the fed-kmeans
+    init and every round's E-step run on each device's own clients, and
+    each round ends in one all-reduce of the statistics (DESIGN.md §9).
     """
 
     def __init__(self, k: int, *, transform=None, async_policy=None,
-                 config: Optional[FitConfig] = None, **overrides):
+                 mesh=None, config: Optional[FitConfig] = None,
+                 **overrides):
+        if mesh is not None and async_policy is not None:
+            raise ValueError("DEM on a mesh runs the synchronous round "
+                             "loop; async_policy takes no mesh")
         self.k = _as_int(k, "k")
         self.transform = transform
         self.async_policy = async_policy
+        self.mesh = mesh
         self.config = _make_config(config, overrides)
         # one copy of the strategy rule: construction-time validation
         # delegates to the core resolver (input-type resolution of "auto"
@@ -424,12 +434,18 @@ class DEM:
         over a :class:`ClientSplit` or list of per-client
         :class:`DataSource`\\ s -> :class:`repro.core.dem.DEMResult`.
         With an ``async_policy`` (:class:`repro.fed.AsyncPolicy`) the
-        rounds run buffered-asynchronously (``repro.fed.run_async``)."""
-        _classify(clients, "DEM.run", ("split", "sources"))
+        rounds run buffered-asynchronously (``repro.fed.run_async``).
+        With a ``mesh`` the split's data and mask are placed over its
+        ``"data"`` axis first, which costs nothing where they already lie
+        so; the client count must divide by the axis' size."""
+        _classify(clients, "DEM.run",
+                  ("split",) if self.mesh is not None else
+                  ("split", "sources"))
         key = _resolve_key(key, self.config)
         self.result_ = dem_cfg(key, clients, self.config, self.k,
                                transform=self.transform,
-                               async_policy=self.async_policy)
+                               async_policy=self.async_policy,
+                               mesh=self.mesh)
         return self.result_
 
     @property

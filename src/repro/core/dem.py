@@ -37,10 +37,11 @@ import jax.numpy as jnp
 from repro.core.config import (FitConfig, is_source_list, resolve_backend,
                                resolve_estep_backend)
 from repro.core.em import (computed_lanes, e_step_stats, fit_gmm,
-                           init_from_means, m_step, prepare_rows,
-                           prepared_bytes)
+                           init_from_means, init_from_means_sharded, m_step,
+                           prepare_rows, prepared_bytes)
 from repro.core.gmm import GMM
-from repro.core.kmeans import federated_kmeans
+from repro.core.kmeans import (federated_kmeans, federated_kmeans_sharded,
+                               gathered_floats)
 from repro.core.partition import ClientSplit
 from repro.data.sources import ConcatSource, DataSource
 from repro.fed.ledger import (CommStats, dtype_itemsize, gmm_payload_floats,
@@ -139,6 +140,32 @@ def fed_kmeans_centers(key: jax.Array, split: ClientSplit, k: int,
                             chunk_size=chunk_size)
 
 
+def init_sharded(key: jax.Array, backend, k: int, init: str, *,
+                 covariance_type: str = "diag", reg_covar: float = 1e-6,
+                 chunk: Optional[int] = None) -> GMM:
+    """The round-0 model of DEM-style strategies on sharded clients
+    (``repro.fed.runtime.ShardedClients``), where every kernel has to run
+    inside ``shard_map``: init 3's centers from
+    :func:`~repro.core.kmeans.federated_kmeans_sharded` (the
+    single-process key schedule: ``key`` is what
+    :func:`federated_kmeans` would get), or init 1's, then the data
+    moments by :func:`~repro.core.em.init_from_means_sharded`."""
+    if init == "fed-kmeans":
+        centers = federated_kmeans_sharded(
+            key, backend.data, backend.mask, mesh=backend.mesh, k_global=k,
+            axis=backend.axis, chunk_size=chunk)
+    elif init == "separated":
+        centers = max_separated_centers(key, k, backend.dim)
+    else:
+        raise ValueError(
+            f"DEM init {init!r} on sharded clients: use 'fed-kmeans' or "
+            f"'separated' ('pilot' uploads raw rows to one server)")
+    return init_from_means_sharded(
+        centers, backend.data, backend.mask, mesh=backend.mesh,
+        axis=backend.axis, covariance_type=covariance_type,
+        reg_covar=reg_covar)
+
+
 # ----------------------------------------------------------------------
 # DEM as a federation strategy
 # ----------------------------------------------------------------------
@@ -200,6 +227,12 @@ class DEMStrategy:
             return self.state_from_gmm(gmm0)
         data, mask = backend.data, backend.mask
         d = data.shape[-1]
+        if backend.kind == "sharded":
+            return self.state_from_gmm(
+                init_sharded(k_init, backend, self.k, self.init,
+                             covariance_type=self.covariance_type,
+                             reg_covar=self.reg_covar, chunk=self.chunk),
+                dtype=data.dtype)
         if self.init == "separated":
             centers = max_separated_centers(k_init, self.k, d)
         elif self.init == "pilot":
@@ -261,13 +294,16 @@ class DEMStrategy:
 
     def prepared_bytes(self, backend, phase: str):
         """Device bytes of the clients' rows padded once for the kernels:
-        in the ``"loop"`` phase the E-step's slabs (split clients), in the
-        ``"init"`` phase the fed-kmeans init's Lloyd loops' (resident
-        clients). None where the kernels get raw arrays."""
+        in the ``"loop"`` phase the E-step's slabs, in the ``"init"``
+        phase the fed-kmeans init's Lloyd loops' (resident clients; on
+        sharded clients, one chip's). None where the kernels get raw
+        arrays."""
         if backend.host:
             return None
-        c, n, d = backend.num_clients, backend.data.shape[1], backend.dim
-        if phase == "loop" and backend.kind == "split":
+        n, d = backend.data.shape[1], backend.dim
+        c = backend.clients_per_shard if backend.kind == "sharded" \
+            else backend.num_clients
+        if phase == "loop":
             each = prepared_bytes(n, d, self._estep(), self.chunk)
         elif phase == "init" and self.init == "fed-kmeans":
             # federated_kmeans assigns on the "auto" backend
@@ -276,6 +312,14 @@ class DEMStrategy:
         else:
             each = None
         return None if each is None else c * each
+
+    def gathered_bytes(self, backend):
+        """Bytes the fed-kmeans init's ``all_gather`` brings to each chip of
+        sharded clients: every client's k centers and cluster sizes."""
+        if backend.kind != "sharded" or self.init != "fed-kmeans":
+            return None
+        return gathered_floats(backend.num_clients, self.k, backend.dim) \
+            * dtype_itemsize(backend.data.dtype)
 
     def server_combine(self, state: DEMState, stats) -> DEMState:
         gmm = m_step(stats, state.reg_covar)
@@ -310,7 +354,7 @@ class DEMStrategy:
         if self.init == "fed-kmeans":
             # one-shot warm start: every client uploads its k local
             # centers + k cluster sizes (Dennis et al. '21)
-            init_up = pop * (self.k * d + self.k)
+            init_up = gathered_floats(pop, self.k, d)
         elif self.init == "pilot":
             init_up = PILOT_ROWS * d   # raw pilot rows to the server
         else:  # "separated": server-side construction, no uplink
@@ -334,7 +378,7 @@ class DEMStrategy:
 
 
 def dem_cfg(key: jax.Array, clients, config: FitConfig, k: int,
-            transform=None, async_policy=None) -> DEMResult:
+            transform=None, async_policy=None, mesh=None) -> DEMResult:
     """Run DEM — the cfg-core behind ``repro.api.DEM``, dispatching on the
     client input type (:class:`ClientSplit` vs list of
     :class:`DataSource`) through the federation runtime. The init strategy
@@ -343,7 +387,9 @@ def dem_cfg(key: jax.Array, clients, config: FitConfig, k: int,
     uploads raw rows). ``async_policy`` (a
     :class:`repro.fed.AsyncPolicy`) reroutes the rounds through the
     buffered asynchronous driver (``repro.fed.run_async``, DESIGN.md
-    §12); None keeps the synchronous loop."""
+    §12); None keeps the synchronous loop. A ``mesh`` shards a split's
+    clients over its ``"data"`` axis (``ShardedClients``, DESIGN.md §9):
+    one ``psum`` of the statistics per round."""
     sources = is_source_list(clients)
     if not sources and not isinstance(clients, ClientSplit):
         raise TypeError(
@@ -361,7 +407,7 @@ def dem_cfg(key: jax.Array, clients, config: FitConfig, k: int,
                          transform=transform, **async_policy.driver_kwargs())
     return run_rounds(strategy, clients, key=key,
                       max_rounds=config.resolve_max_iter("em"),
-                      transform=transform)
+                      transform=transform, mesh=mesh)
 
 
 def dem(key: jax.Array, split: ClientSplit, k: int, init: int = 3,

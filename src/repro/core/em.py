@@ -34,6 +34,7 @@ from typing import Callable, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core.config import (DEFAULT_SOURCE_CHUNK, ENGINE_BACKENDS,
                                FitConfig, require_array_weights,
@@ -669,6 +670,46 @@ def init_from_means(means: jax.Array, x: jax.Array,
     mean = jnp.sum(x * w[:, None], axis=0) / wsum
     var = jnp.sum((x - mean) ** 2 * w[:, None], axis=0) / wsum + reg_covar
     weights = jnp.full((k,), 1.0 / k, x.dtype)
+    if covariance_type == "diag":
+        covs = jnp.broadcast_to(var, (k, d))
+    else:
+        covs = jnp.broadcast_to(jnp.diag(var), (k, d, d))
+    return GMM(weights, means, covs)
+
+
+@partial(jax.jit, static_argnames=("mesh", "axis", "covariance_type"))
+def init_from_means_sharded(means: jax.Array, client_data: jax.Array,
+                            client_weights: jax.Array, *, mesh,
+                            axis: str = "data",
+                            covariance_type: str = "diag",
+                            reg_covar: float = 1e-6) -> GMM:
+    """:func:`init_from_means` over ``(C, N, d)`` clients sharded on the
+    ``axis`` of ``mesh``: each shard reduces its own rows to their weight,
+    sum and squared deviations from the shard's own mean, and one ``psum``
+    combines them (Chan et al.'s pairwise update: the deviations of the
+    shards' means from the whole mean add the between-shard part). The
+    same variance as the resident two-pass form up to rounding; the
+    returned model is replicated over the mesh."""
+    k, d = means.shape
+
+    def shard_fn(x_s, w_s):
+        x = x_s.reshape(-1, d)
+        w = w_s.reshape(-1)
+        n = jnp.sum(w)
+        s1 = jnp.sum(x * w[:, None], axis=0)
+        mean = s1 / jnp.maximum(n, 1e-12)
+        m2 = jnp.sum((x - mean) ** 2 * w[:, None], axis=0)
+        # === the init's one all-reduce ===
+        return jax.lax.psum((n, s1, m2, s1 * mean), axis)
+
+    spec = P(axis)
+    n, s1, m2, between = jax.shard_map(
+        shard_fn, mesh=mesh, in_specs=(spec, spec), out_specs=P(),
+        check_vma=False)(client_data, client_weights)
+    wsum = jnp.maximum(n, 1e-12)
+    mean = s1 / wsum
+    var = (m2 + between - s1 * mean) / wsum + reg_covar
+    weights = jnp.full((k,), 1.0 / k, client_data.dtype)
     if covariance_type == "diag":
         covs = jnp.broadcast_to(var, (k, d))
     else:

@@ -24,6 +24,7 @@ from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core.config import (FitConfig, is_source_list,
                                require_array_weights, resolve_backend,
@@ -412,6 +413,61 @@ def federated_kmeans(key: jax.Array, client_data, k_global: int,
     res = kmeans(keys[-1], flat_centers, k_global,
                  sample_weight=flat_sizes, max_iter=max_iter)
     return res.centers
+
+
+@partial(jax.jit, static_argnames=("mesh", "axis", "k_global", "k_local",
+                                   "max_iter", "chunk_size",
+                                   "assign_backend"))
+def federated_kmeans_sharded(key: jax.Array, client_data: jax.Array,
+                             client_weights: jax.Array, *, mesh,
+                             k_global: int, axis: str = "data",
+                             k_local: Optional[int] = None,
+                             max_iter: int = 100,
+                             chunk_size: Optional[int] = None,
+                             assign_backend: str = "auto") -> jax.Array:
+    """:func:`federated_kmeans` over clients sharded on the ``axis`` of
+    ``mesh``, with its key schedule, so both give the same centers up to
+    summation order. All of it runs inside ``shard_map``, where the
+    kernels see one chip's arrays: each shard vmaps the local k-means
+    over its own clients, one ``all_gather`` brings every client's
+    (k_local, d) centers and cluster sizes to every shard, and each shard
+    runs the server clustering on them alike. Returns (k_global, d)
+    centers, replicated over the mesh."""
+    c, _, d = client_data.shape
+    k_local = k_local or k_global
+    per_shard = c // mesh.shape[axis]
+
+    def shard_fn(key, x_s, w_s):
+        # every shard draws the whole schedule and keeps its own clients'
+        # keys: slicing a sharded key array would be a collective of its own
+        keys = jax.random.split(key, c + 1)
+        keys_s = jax.lax.dynamic_slice_in_dim(
+            keys, jax.lax.axis_index(axis) * per_shard, per_shard)
+
+        def local(kk, x, w):
+            res = kmeans(kk, x, k_local, sample_weight=w, max_iter=max_iter,
+                         chunk_size=chunk_size,
+                         assign_backend=assign_backend)
+            return jnp.concatenate([res.centers, res.cluster_sizes[:, None]],
+                                   axis=1)
+        # === the init's one communication: (C, k_local, d + 1) ===
+        local_all = jax.lax.all_gather(jax.vmap(local)(keys_s, x_s, w_s),
+                                       axis, tiled=True)
+        res = kmeans(keys[-1], local_all[..., :d].reshape(-1, d), k_global,
+                     sample_weight=local_all[..., d].reshape(-1),
+                     max_iter=max_iter)
+        return res.centers
+
+    spec = P(axis)
+    return jax.shard_map(shard_fn, mesh=mesh, in_specs=(P(), spec, spec),
+                         out_specs=P(), check_vma=False)(
+                             key, client_data, client_weights)
+
+
+def gathered_floats(clients: int, k_local: int, d: int) -> int:
+    """Floats a one-shot federated k-means collects: every client's
+    ``k_local`` centers and cluster sizes (Dennis et al. '21)."""
+    return clients * (k_local * d + k_local)
 
 
 # ----------------------------------------------------------------------

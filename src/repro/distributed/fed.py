@@ -36,11 +36,11 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.config import FitConfig, resolve_backend
 from repro.core.dem import DEMStrategy, _resolve_init
-from repro.core.em import fit_gmm_cfg, init_from_means
+from repro.core.em import fit_gmm_cfg, init_from_means_sharded
 from repro.core.gmm import GMM, merge_gmms_stacked
 from repro.data.sources import SyntheticGMMSource
 from repro.fed.cohort import make_sampler
-from repro.fed.runtime import run_rounds
+from repro.fed.runtime import make_backend, run_rounds
 from repro.fed.strategies import (FedEMResult, FedEMStrategy,
                                   FedKMeansResult, FedKMeansStrategy,
                                   _resolve_fedkmeans_init)
@@ -130,7 +130,7 @@ def fedgen_sharded(mesh, key, data, mask, k: int, k_global: int,
     return ShardedFedResult(res.gmm, w_all, mu_all, cov_all)
 
 
-def dem_sharded(mesh, key, data, mask, k: int, init_centers,
+def dem_sharded(mesh, key, data, mask, k: int, init_centers=None,
                 max_rounds: int = 100, tol: float = 1e-3,
                 reg_covar: float = 1e-6,
                 estep_backend: str = "auto",
@@ -140,35 +140,45 @@ def dem_sharded(mesh, key, data, mask, k: int, init_centers,
     """Distributed EM over the mesh: one psum of sufficient statistics per
     EM round (the iterative baseline's communication pattern).
 
-    Since §9 this is a :class:`~repro.core.dem.DEMStrategy` on the shared
-    round driver — shard_map is the client backend, not a third copy of
-    the loop. ``init_centers`` are the caller-chosen global centers (the
-    scheme inits live in :func:`repro.core.dem.dem_cfg`); ``key`` is
-    unused on this path and kept for signature stability. With an integer
-    chunk size each shard streams its clients' rows through the engine so
-    per-round shard memory is bounded by (chunk_size, K) rather than
-    (N, K) — the psum payload is unchanged (SufficientStats is already
-    the reduced form).
+    The keyword spelling of ``repro.api.DEM(k, mesh=mesh)``: a
+    :class:`~repro.core.dem.DEMStrategy` on the shared round driver with
+    shard_map as the client backend. ``init_centers`` overrides the
+    strategy's init with caller-chosen global centers (the data moments
+    around them are still computed on the shards); without them ``key``
+    seeds the init ``config.init`` names, as in ``repro.api.DEM``. With an
+    integer chunk size each shard streams its clients' rows through the
+    engine so per-round shard memory is bounded by (chunk_size, K) rather
+    than (N, K) — the psum payload is unchanged (SufficientStats is
+    already the reduced form).
     """
     cfg = config if config is not None else FitConfig.from_legacy(
         backend=estep_backend, chunk_size=chunk_size, tol=tol,
         max_iter=max_rounds, reg_covar=reg_covar)
-    data, mask = jnp.asarray(data), jnp.asarray(mask)
-    d = data.shape[-1]
     strategy = DEMStrategy(
         k=k, covariance_type=cfg.covariance_type, backend=cfg.backend,
-        chunk=cfg.resolve_chunk(source=False), host=False,
+        chunk=cfg.resolve_chunk(source=False),
+        init=_resolve_init(cfg.init, sources=False), host=False,
         tol=cfg.resolve_tol("em"), reg_covar=cfg.reg_covar)
-    flat = data.reshape(-1, d)
-    flat_w = mask.reshape(-1)
-    gmm0 = init_from_means(init_centers, flat, flat_w,
-                           covariance_type=cfg.covariance_type,
-                           reg_covar=cfg.reg_covar)
-    res = run_rounds(strategy, (data, mask), mesh=mesh,
-                     state0=strategy.state_from_gmm(gmm0, dtype=data.dtype),
+    backend = make_backend((data, mask), mesh)
+    res = run_rounds(strategy, (backend.data, backend.mask), key=key,
+                     mesh=mesh, state0=_state_around(strategy, backend,
+                                                     init_centers, cfg),
                      max_rounds=cfg.resolve_max_iter("em"),
                      transform=transform)
     return res.global_gmm, res.n_rounds
+
+
+def _state_around(strategy, backend, init_centers, cfg):
+    """Round-0 state around caller-chosen centers, with the data moments
+    computed on the shards; None (the strategy's own init) without
+    them."""
+    if init_centers is None:
+        return None
+    gmm0 = init_from_means_sharded(
+        jnp.asarray(init_centers), backend.data, backend.mask,
+        mesh=backend.mesh, axis=backend.axis,
+        covariance_type=cfg.covariance_type, reg_covar=cfg.reg_covar)
+    return strategy.state_from_gmm(gmm0, dtype=backend.data.dtype)
 
 
 def fedem_sharded(mesh, key, data, mask, k: int, *,
@@ -188,28 +198,22 @@ def fedem_sharded(mesh, key, data, mask, k: int, *,
     (which resolves exactly as in single-process FedEM: "auto" ->
     one-shot fed-kmeans)."""
     cfg = config if config is not None else FitConfig()
-    data, mask = jnp.asarray(data), jnp.asarray(mask)
+    backend = make_backend((data, mask), mesh)
     strategy = FedEMStrategy(
         k=k, covariance_type=cfg.covariance_type, backend=cfg.backend,
         chunk=cfg.resolve_chunk(source=False),
         init=_resolve_init(cfg.init, sources=False), host=False,
         tol=cfg.resolve_tol("em"), reg_covar=cfg.reg_covar,
         participation=float(participation), local_epochs=int(local_epochs),
-        n_clients=data.shape[0])
+        n_clients=backend.num_clients)
     sampler = None
     if strategy.participation < 1.0:
-        sampler = make_sampler(cohort, data.shape[0],
+        sampler = make_sampler(cohort, backend.num_clients,
                                strategy.cohort_size(), seed=cohort_seed)
-    state0 = None
-    if init_centers is not None:
-        d = data.shape[-1]
-        gmm0 = init_from_means(init_centers, data.reshape(-1, d),
-                               mask.reshape(-1),
-                               covariance_type=cfg.covariance_type,
-                               reg_covar=cfg.reg_covar)
-        state0 = strategy.state_from_gmm(gmm0, dtype=data.dtype)
-    return run_rounds(strategy, (data, mask), key=key, mesh=mesh,
-                      state0=state0,
+    return run_rounds(strategy, (backend.data, backend.mask), key=key,
+                      mesh=mesh,
+                      state0=_state_around(strategy, backend, init_centers,
+                                           cfg),
                       max_rounds=cfg.resolve_max_iter("em"),
                       sampler=sampler, stragglers=stragglers,
                       transform=transform)

@@ -50,7 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.data.sources import DataSource
 from repro.fed.ledger import CommStats, RoundPayload
@@ -90,11 +90,14 @@ class FederationStrategy(Protocol):
     - ``prepare_client(x, w)`` (optional) — one resident client's rows as
       ``local_step`` reads them, built once before the jitted round loop
       (the fused kernel's padded slab, or ``x`` itself); absent, or on
-      sharded and source clients, ``local_step`` gets the rows.
+      source clients, ``local_step`` gets the rows.
     - ``prepared_bytes(backend, phase) -> int | None`` (optional) — the
       device bytes of padded kernel operands the ``"init"`` or
-      ``"loop"`` phase builds once, for that phase's profiler span; left
-      out of it when absent or None.
+      ``"loop"`` phase builds once (one chip's, on sharded clients), for
+      that phase's profiler span; left out of it when absent or None.
+    - ``gathered_bytes(backend) -> int | None`` (optional) — the bytes
+      the init's ``all_gather`` carries on sharded clients, for the
+      ``repro.rounds.init`` span; left out of it when absent or None.
     - ``round_payload(backend, state) -> RoundPayload`` — what one round
       moves; the driver multiplies by the realized round count.
     - ``finalize(state, n_rounds, converged, comm) -> result``.
@@ -322,31 +325,55 @@ class SourceClients:
 @jax.tree_util.register_pytree_node_class
 class ShardedClients:
     """Mesh-sharded clients: the client axis of ``data (C, N, d)`` maps to
-    shards of ``axis``; the per-round combine is literally one
-    ``jax.lax.psum`` across the mesh — the collective pattern the sharded
-    DEM runtime always had, now produced by the same driver as everything
-    else."""
+    shards of ``axis``, ``C / shards`` clients each; the per-round combine
+    is literally one ``jax.lax.psum`` across the mesh — the collective
+    pattern the sharded DEM runtime always had, now produced by the same
+    driver as everything else. As on :class:`SplitClients`, each client's
+    step reads ``rows`` once :meth:`prepared` has built them."""
 
     kind = "sharded"
     host = False
 
     def __init__(self, data: jax.Array, mask: jax.Array, mesh,
-                 axis: str = "data"):
+                 axis: str = "data", split=None, rows=None):
         self.data = data
         self.mask = mask
         self.mesh = mesh
         self.axis = axis
+        self.split = split  # the original ClientSplit (host metadata)
+        self.rows = rows
 
     def tree_flatten(self):
-        return (self.data, self.mask), (self.mesh, self.axis)
+        return (self.data, self.mask, self.rows), (self.mesh, self.axis)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(*children, *aux)
+        data, mask, rows = children
+        return cls(data, mask, *aux, rows=rows)
+
+    def prepared(self, prepare_client) -> "ShardedClients":
+        """These clients with ``prepare_client(x, w)`` of every client's
+        rows as the rows its step reads, built once: each shard prepares
+        its own clients inside ``shard_map``, and the result stays sharded
+        like ``data``."""
+        spec = P(self.axis)
+        rows = jax.shard_map(jax.vmap(prepare_client), mesh=self.mesh,
+                             in_specs=(spec, spec), out_specs=spec,
+                             check_vma=False)(self.data, self.mask)
+        return ShardedClients(self.data, self.mask, self.mesh, self.axis,
+                              self.split, rows)
 
     @property
     def num_clients(self) -> int:
         return self.data.shape[0]
+
+    @property
+    def shards(self) -> int:
+        return self.mesh.shape[self.axis]
+
+    @property
+    def clients_per_shard(self) -> int:
+        return self.num_clients // self.shards
 
     @property
     def dim(self) -> int:
@@ -354,7 +381,8 @@ class ShardedClients:
 
     @property
     def sizes(self):
-        return jnp.sum(self.mask, axis=1)
+        return self.split.sizes if self.split is not None else jnp.sum(
+            self.mask, axis=1)
 
     @property
     def population_clients(self) -> int:
@@ -367,6 +395,7 @@ class ShardedClients:
         knobs ride the shard_map replicated), then ONE psum."""
         axis = self.axis
         c = self.data.shape[0]
+        rows = self.data if self.rows is None else self.rows
         # the transform key/params enter shard_fn as replicated operands
         # (shard_map wants operands explicit, not closed over)
         tk = jnp.zeros((), jnp.int32) if tkey is None else tkey
@@ -389,8 +418,7 @@ class ShardedClients:
                                in_specs=(P(), P(axis), P(axis), P(axis),
                                          P(axis), P(), P()),
                                out_specs=P(), check_vma=False)
-            return fn(state, jnp.arange(c), w, self.data, self.mask,
-                      tk, tp)
+            return fn(state, jnp.arange(c), w, rows, self.mask, tk, tp)
 
         # Cohort execution: the cohort (and its weights) are replicated;
         # each shard gathers the cohort members IT owns from its local
@@ -408,7 +436,7 @@ class ShardedClients:
             step = _wrap_step(local_step, state, transform, tp_r, tk_r,
                               cohort_r)
             per = jax.vmap(step)(
-                jnp.take(data_s, safe, axis=0),
+                jax.tree.map(lambda r: jnp.take(r, safe, axis=0), data_s),
                 jnp.take(mask_s, safe, axis=0), cohort_r)
             gate = owned.astype(w_r.dtype) * w_r
             per = jax.tree.map(lambda s: s * _weight_bcast(gate, s), per)
@@ -421,8 +449,7 @@ class ShardedClients:
                            in_specs=(P(), P(axis), P(), P(), P(axis),
                                      P(axis), P(), P()),
                            out_specs=P(), check_vma=False)
-        return fn(state, jnp.arange(c), cohort, w, self.data, self.mask,
-                  tk, tp)
+        return fn(state, jnp.arange(c), cohort, w, rows, self.mask, tk, tp)
 
 
 def slab_counters(backend, lanes_computed: Optional[int] = None,
@@ -436,11 +463,14 @@ def slab_counters(backend, lanes_computed: Optional[int] = None,
     layer that picks its backend gives it (left out where none does);
     ``prepared_bytes``, the device bytes of padded kernel operands built
     once for the phase, as that layer gives it (left out where the
-    kernels get raw arrays). Taken from shapes and host arrays alone: a
-    count that lives on the device is left out, never fetched (that would
-    wait for the device). So are a sampled cohort's rows, which change
-    from round to round, and ``rows_computed`` for source clients, whose
-    block padding the engine owns."""
+    kernels get raw arrays; on sharded clients, one chip's). Sharded
+    clients add ``shards`` and ``clients_per_shard``; their other counts
+    are of the whole federation, like every backend's. Taken from shapes
+    and host arrays alone: a count that lives on the device is left out,
+    never fetched (that would wait for the device). So are a sampled
+    cohort's rows, which change from round to round, and
+    ``rows_computed`` for source clients, whose block padding the engine
+    owns."""
     d = int(backend.dim)
     c = int(backend.num_clients if cohort_size is None else cohort_size)
     out = {"clients": c, "lanes": d}
@@ -450,6 +480,8 @@ def slab_counters(backend, lanes_computed: Optional[int] = None,
         out["prepared_bytes"] = int(prepared_bytes)
     if backend.kind != "sources":
         out["rows_computed"] = c * int(backend.data.shape[1])
+    if backend.kind == "sharded":
+        out.update(shard_counters(backend))
     if cohort_size is None:
         sizes = backend.sizes if backend.kind == "sources" \
             else getattr(getattr(backend, "split", None), "sizes", None)
@@ -458,15 +490,31 @@ def slab_counters(backend, lanes_computed: Optional[int] = None,
     return out
 
 
+def shard_counters(backend) -> dict:
+    """How sharded clients lie on the mesh, for a profiler span:
+    ``shards`` (the mesh axis' size) and ``clients_per_shard``."""
+    return {"shards": int(backend.shards),
+            "clients_per_shard": int(backend.clients_per_shard)}
+
+
 def make_backend(clients, mesh=None, axis: str = "data"):
     """THE client dispatch: ClientSplit -> :class:`SplitClients`, a list
-    of DataSources -> :class:`SourceClients`, ``(data, mask)`` arrays with
-    a ``mesh`` -> :class:`ShardedClients`."""
-    if mesh is not None:
-        data, mask = clients
-        return ShardedClients(jnp.asarray(data), jnp.asarray(mask), mesh,
-                              axis)
+    of DataSources -> :class:`SourceClients`; with a ``mesh``, a
+    ClientSplit or ``(data, mask)`` arrays -> :class:`ShardedClients`,
+    their client axis placed over ``axis`` (no copy where it already
+    lies so). The client count must divide by the axis' size."""
     from repro.core.partition import ClientSplit  # call-time: core sits above
+    if mesh is not None:
+        split = clients if isinstance(clients, ClientSplit) else None
+        data, mask = clients[:2] if split is not None else clients
+        shards = mesh.shape[axis]
+        if data.shape[0] % shards:
+            raise ValueError(
+                f"{data.shape[0]} clients do not divide over the {shards} "
+                f"shards of mesh axis {axis!r}")
+        data, mask = jax.device_put((data, mask),
+                                    NamedSharding(mesh, P(axis)))
+        return ShardedClients(data, mask, mesh, axis, split)
     if isinstance(clients, ClientSplit):
         return SplitClients(jnp.asarray(clients.data),
                             jnp.asarray(clients.mask), clients)
@@ -547,10 +595,11 @@ def _iterate_jit(strategy, backend, state0, max_rounds: int,
     ``tparams`` as traced leaves and the sampler/straggler/transform
     PRNG keys (``skey``/``dkey``/``tkey``) are traced, so sweeping knobs
     or reseeding does not recompile. A strategy's ``prepare_client``
-    runs on split clients once, before the loop, whose body closes over
-    the result (never a carry element)."""
+    runs on the clients once, before the loop (on sharded clients each
+    shard its own), whose body closes over the result (never a carry
+    element)."""
     prepare = getattr(strategy, "prepare_client", None)
-    if prepare is not None and backend.kind == "split":
+    if prepare is not None:
         backend = backend.prepared(prepare)
 
     def one_round(state, rnd):
@@ -688,18 +737,24 @@ def run_rounds(strategy, clients, *, key: Optional[jax.Array] = None,
                 "straggler handling needs a round structure; one-shot "
                 "strategies take no straggler policy")
         dkey = jax.random.key(int(getattr(stragglers, "seed", 0)))
-    # the phases' bytes of padded kernel operands built once, as the
-    # strategy, which picks the clients' backend, gives them
+    # the phases' bytes of padded kernel operands built once, and of the
+    # init's all_gather, as the strategy, which picks the clients' backend
+    # and the init, gives them
     prepared = getattr(strategy, "prepared_bytes", None)
+    gathered = getattr(strategy, "gathered_bytes", None)
 
     def prepared_in(phase):
         return None if prepared is None else prepared(backend, phase)
 
+    ledger_backend = backend if sampler is None \
+        else _CohortView(backend, sampler.cohort_size)
     if state0 is None:
-        init_bytes = prepared_in("init")
-        with TraceAnnotation("repro.rounds.init", **(
-                {} if init_bytes is None
-                else {"prepared_bytes": init_bytes})):
+        init = {"prepared_bytes": prepared_in("init")}
+        if backend.kind == "sharded":
+            init.update(shard_counters(backend), allgather_bytes=(
+                None if gathered is None else gathered(backend)))
+        with TraceAnnotation("repro.rounds.init", **{
+                name: v for name, v in init.items() if v is not None}):
             state0 = strategy.init_state(key, backend)
 
     cohort_size = None if sampler is None else sampler.cohort_size
@@ -709,6 +764,12 @@ def run_rounds(strategy, clients, *, key: Optional[jax.Array] = None,
     slab = None if one_shot else slab_counters(
         backend, None if lanes is None else lanes(int(backend.dim)),
         cohort_size, prepared_in("loop"))
+    if backend.kind == "sharded" and not one_shot:
+        # one round's psum carries one client's payload, summed
+        per_round = strategy.round_payload(ledger_backend, state0)
+        slab["allreduce_bytes"] = (per_round.uplink_floats
+                                   // ledger_backend.num_clients
+                                   * per_round.itemsize)
     if one_shot:
         if transform is not None:
             state = strategy.run_once(state0, backend,
@@ -754,8 +815,6 @@ def run_rounds(strategy, clients, *, key: Optional[jax.Array] = None,
         if post is not None and not one_shot:
             state = post(state, backend)
 
-        ledger_backend = backend if sampler is None \
-            else _CohortView(backend, sampler.cohort_size)
         payload = strategy.round_payload(ledger_backend, state)
         if transform is not None:
             # transform-aware ledger: the uplink direction carries the

@@ -44,7 +44,8 @@ from repro.core.config import FitConfig, is_source_list, resolve_backend
 from repro.core.dem import DEMStrategy, _resolve_init, max_separated_centers
 from repro.core.em import computed_lanes, e_step_stats, m_step
 from repro.core.gmm import GMM
-from repro.core.kmeans import federated_kmeans, lloyd_round_stats
+from repro.core.kmeans import (federated_kmeans, federated_kmeans_sharded,
+                               gathered_floats, lloyd_round_stats)
 from repro.core.partition import ClientSplit
 from repro.fed.cohort import make_sampler
 from repro.fed.ledger import (CommStats, RoundPayload, dtype_itemsize,
@@ -287,6 +288,10 @@ class FedKMeansStrategy:
         elif backend.kind == "sources":
             centers = federated_kmeans(k_init, list(backend.sources), self.k,
                                        chunk_size=self.chunk)
+        elif backend.kind == "sharded":
+            centers = federated_kmeans_sharded(
+                k_init, backend.data, backend.mask, mesh=backend.mesh,
+                k_global=self.k, axis=backend.axis, chunk_size=self.chunk)
         else:
             centers = federated_kmeans(k_init, backend.data, self.k,
                                        client_weights=backend.mask,
@@ -301,6 +306,9 @@ class FedKMeansStrategy:
     def lanes_computed(self, d: int) -> int:
         """Feature width a client's assignment sweep computes over."""
         return computed_lanes(d, self.assign_backend)
+
+    # the fed-kmeans warm start gathers as DEM's does
+    gathered_bytes = DEMStrategy.gathered_bytes
 
     def local_step(self, state: FedKMeansState, x, w, idx):
         return lloyd_round_stats(state.centers, x, w, self.assign_backend,
@@ -350,7 +358,7 @@ class FedKMeansStrategy:
         # free): every scheme broadcasts the k·d round-0 centers to the
         # population; the fed-kmeans warm start first collects each
         # client's k local centers + k cluster sizes (Dennis et al.).
-        warm_up = pop * (self.k * d + self.k) \
+        warm_up = gathered_floats(pop, self.k, d) \
             if self.init == "fed-kmeans" else 0
         return RoundPayload(
             uplink_floats=c * label_payload_floats(self.k, d),

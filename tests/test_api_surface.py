@@ -48,6 +48,19 @@ EXPECTED_FITCONFIG_FIELDS = [
     ("seed", 0),
 ]
 
+# The federated runners' constructor keywords, in order: each is a knob
+# every caller may pass, so adding one is deliberate and removing one is
+# a breaking change. ``mesh`` shards DEM's clients over a device mesh.
+EXPECTED_RUNNER_KEYWORDS = {
+    "FedGenGMM": ["k_clients", "k_global", "k_candidates", "h", "synthetic",
+                  "dp", "transform", "config", "overrides"],
+    "DEM": ["k", "transform", "async_policy", "mesh", "config", "overrides"],
+    "FedEM": ["k", "participation", "local_epochs", "cohort", "cohort_seed",
+              "stragglers", "transform", "async_policy", "config",
+              "overrides"],
+    "FedKMeans": ["k", "transform", "config", "overrides"],
+}
+
 # Deprecation shims must never leak into the facade: they live in
 # repro.core, warn on use, and forward — the public surface stays the
 # estimator/runner set above.
@@ -112,6 +125,11 @@ class TestFacadeShape:
         for cls in (GMMEstimator, KMeansEstimator, FedGenGMM, DEM, FedEM,
                     FedKMeans):
             assert "config" in inspect.signature(cls.__init__).parameters
+
+    def test_runner_keywords(self):
+        for name, want in EXPECTED_RUNNER_KEYWORDS.items():
+            params = inspect.signature(getattr(api, name).__init__).parameters
+            assert list(params)[1:] == want, name
 
     def test_strategy_seam_signature(self):
         params = inspect.signature(api.fit_federated).parameters

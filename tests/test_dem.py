@@ -84,3 +84,43 @@ class TestInits:
         dr = dem(jax.random.key(0), split, 3, init=1)
         assert dr.comm.rounds == int(dr.n_rounds)
         assert dr.comm.uplink_floats > dr.comm.rounds  # per-round stats
+
+
+class TestMeshFacade:
+    """``repro.api.DEM(k, mesh=...)``: what it refuses before it runs."""
+
+    def test_clients_must_divide_over_the_shards(self, setup):
+        from types import SimpleNamespace
+        from repro.api import DEM
+        _, _, split = setup
+        four = SimpleNamespace(shape={"data": 4})
+        with pytest.raises(ValueError, match="6 clients .* 4 shards"):
+            DEM(3, mesh=four).run(split, key=jax.random.key(0))
+
+    def test_mesh_takes_no_async_policy_or_sources(self, setup):
+        from repro.api import DEM
+        from repro.data.sources import ArraySource
+        from repro.fed import AsyncPolicy
+        _, _, split = setup
+        mesh = jax.make_mesh((1,), ("data",))
+        with pytest.raises(ValueError, match="async_policy"):
+            DEM(3, mesh=mesh, async_policy=AsyncPolicy())
+        sources = [ArraySource(np.asarray(split.data[c, :n]))
+                   for c, n in enumerate(split.sizes)]
+        with pytest.raises(TypeError, match="ClientSplit"):
+            DEM(3, mesh=mesh).run(sources, key=jax.random.key(0))
+
+    def test_one_shard_mesh_is_the_split_run(self, setup):
+        """On a mesh of one device the sharded path sums the same clients
+        in the same order as the split path."""
+        from repro.api import DEM
+        _, _, split = setup
+        key = jax.random.key(2)
+        one = DEM(3).run(split, key=key)
+        mesh = DEM(3, mesh=jax.make_mesh((1,), ("data",))).run(split, key=key)
+        assert int(one.n_rounds) == int(mesh.n_rounds)
+        for f in ("weights", "means", "covs"):
+            np.testing.assert_allclose(
+                np.asarray(getattr(one.global_gmm, f)),
+                np.asarray(getattr(mesh.global_gmm, f)), rtol=1e-5,
+                atol=1e-6)

@@ -9,6 +9,7 @@ the jitted round loop the phases are ``jax.named_scope``\\ s, which the
 lowered program's locations carry."""
 import glob
 import time
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +23,8 @@ from repro.core.partition import partition
 from repro.data.sources import ArraySource
 from repro.core.em import computed_lanes, fit_prepared_bytes
 from repro.core.kmeans import SEED_ROWS
-from repro.fed.runtime import _iterate_jit, make_backend, slab_counters
+from repro.fed.runtime import (ShardedClients, _iterate_jit, make_backend,
+                               slab_counters)
 from repro.fed.strategies import FedKMeansStrategy
 from repro.kernels.ops import LANES, padded_lanes, slab_bytes
 from repro.serve import ScoreConfig, ScoreRequest, ScoringEngine
@@ -225,6 +227,44 @@ def test_prepared_bytes_on_the_chip(monkeypatch, split):
     assert fit_prepared_bytes(big, D, FitConfig(chunk_size=64)) == seed
     assert fit_prepared_bytes(n, D, FitConfig(covariance_type="full")) == \
         slab_bytes(n, D, weights=False)
+
+
+def test_prepared_bytes_per_chip_on_sharded_clients(monkeypatch, split):
+    """On sharded clients a phase counts the slabs one chip builds: its
+    own clients'."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n = split.data.shape[1]
+    three_shards = SimpleNamespace(shape={"data": CLIENTS})
+    backend = ShardedClients(jnp.asarray(split.data),
+                             jnp.asarray(split.mask), three_shards)
+    dem = DEMStrategy(k=K)
+    assert (backend.shards, backend.clients_per_shard) == (CLIENTS, 1)
+    assert dem.prepared_bytes(backend, "init") == \
+        slab_bytes(n, D, weights=False)
+    assert dem.prepared_bytes(backend, "loop") == slab_bytes(n, D)
+
+
+def test_sharded_dem_spans_count_the_mesh(tmp_path, split):
+    """DEM on a mesh: the init and the loop count the shards and their
+    clients; the init the bytes its all_gather brings (every client's K
+    centers and sizes), the loop the bytes of one round's psum (one
+    client's statistics, summed) and each chip's slabs."""
+    mesh = jax.make_mesh((1,), ("data",))
+    dem = DEM(K, mesh=mesh, config=FitConfig(max_iter=3, backend="fused"))
+    res, spans = traced(tmp_path, lambda: dem.run(split,
+                                                  key=jax.random.key(0)))
+    assert parents(spans) == {name: {None} for name in (
+        "repro.rounds.init", "repro.rounds.loop", "repro.rounds.finalize")}
+    mesh_counters = {"shards": 1, "clients_per_shard": CLIENTS}
+    # the fed-kmeans init assigns on "auto", the reference path on a CPU
+    assert counters(spans, "repro.rounds.init") == [dict(
+        mesh_counters, allgather_bytes=CLIENTS * (K * D + K) * 4)]
+    assert counters(spans, "repro.rounds.loop") == [dict(
+        _slab(split), lanes_computed=LANES, **mesh_counters,
+        prepared_bytes=CLIENTS * slab_bytes(split.data.shape[1], D),
+        allreduce_bytes=(K + 2 * K * D + 2) * 4)]
+    assert counters(spans, "repro.rounds.finalize") == [
+        {"rounds": int(res.n_rounds)}]
 
 
 def test_fedgen_spans_nest_and_count(tmp_path, split):

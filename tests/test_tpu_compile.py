@@ -20,20 +20,24 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
+import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.config import FitConfig
 from repro.core.dem import DEMStrategy
+from repro.core.em import init_from_means_sharded
 from repro.core.fedgen import _train_locals_jit
 from repro.core.gmm import GMM
-from repro.fed.runtime import SplitClients, _iterate_jit
+from repro.core.kmeans import federated_kmeans_sharded
+from repro.fed.runtime import ShardedClients, SplitClients, _iterate_jit
 from repro.kernels import ops
 
 F32 = jnp.float32
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     try:
@@ -46,9 +50,21 @@ def one_chip():
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", enabled)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """The 2x2 host as one mesh axis, ``data``, as a four-chip cell
+    shards its clients."""
+    return Mesh(np.array(topo.devices), ("data",))
 
 
 def _spec(sharding, *shape):
@@ -120,9 +136,9 @@ _PAD_OR_COPY = re.compile(r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* "
                           r"(pad|copy)\(")
 
 
-def loop_pads(hlo: str) -> list:
-    """(op, shape) of every ``pad`` and ``copy`` in a ``while`` body of the
-    compiled program, or in a computation such a body calls."""
+def loop_lines(hlo: str) -> list:
+    """The instructions of every ``while`` body of the compiled program,
+    and of the computations such a body calls."""
     comps, name = {}, None
     for line in hlo.splitlines():
         head = re.match(r"^(?:ENTRY )?%(\S+) .*\{$", line)
@@ -145,13 +161,35 @@ def loop_pads(hlo: str) -> list:
             for one, many in _CALLS.findall(line):
                 todo.extend([one] if one else
                             [c.strip().lstrip("%") for c in many.split(",")])
+    return [line for comp in seen for line in comps[comp]]
+
+
+def loop_pads(hlo: str) -> list:
+    """(op, shape) of every ``pad`` and ``copy`` in a ``while`` body of the
+    compiled program, or in a computation such a body calls."""
     found = []
-    for comp in seen:
-        for line in comps[comp]:
-            m = _PAD_OR_COPY.match(line)
-            if m:
-                shape = tuple(int(v) for v in m.group(1).split(",") if v)
-                found.append((m.group(2), shape))
+    for line in loop_lines(hlo):
+        m = _PAD_OR_COPY.match(line)
+        if m:
+            shape = tuple(int(v) for v in m.group(1).split(",") if v)
+            found.append((m.group(2), shape))
+    return found
+
+
+_COLLECTIVE = re.compile(r" ((?:all-reduce|all-gather|all-to-all|"
+                         r"collective-permute|reduce-scatter)"
+                         r"(?:-start|-done)?)\(")
+
+
+def collectives(lines) -> list:
+    """(op, operand count) of every cross-chip collective among HLO
+    instruction lines; a tuple collective counts its operands."""
+    found = []
+    for line in lines:
+        m = _COLLECTIVE.search(line)
+        if m:
+            operands = line[m.end():].split(")", 1)[0]
+            found.append((m.group(1), operands.count("%")))
     return found
 
 
@@ -199,18 +237,91 @@ def test_fedgen_local_fits_pad_once(one_chip, on_tpu):
     _assert_slab_built_once(hlo, N, d)
 
 
+def _state0(strategy, d, sharding):
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding,
+                                       weak_type=s.weak_type),
+        jax.eval_shape(lambda: strategy.state_from_gmm(
+            GMM(jnp.full((K,), 1.0 / K), jnp.zeros((K, d)),
+                jnp.ones((K, d))), dtype=F32)))
+
+
 def test_dem_round_loop_pads_once(one_chip, on_tpu):
     """DEM's resident round loop: every round's E-step reads the clients'
     slabs, prepared before the loop."""
     d = 38
     strategy = DEMStrategy(k=K)
-    state0 = jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip,
-                                       weak_type=s.weak_type),
-        jax.eval_shape(lambda: strategy.state_from_gmm(
-            GMM(jnp.full((K,), 1.0 / K), jnp.zeros((K, d)),
-                jnp.ones((K, d))), dtype=F32)))
     backend = SplitClients(_spec(one_chip, C, N, d), _spec(one_chip, C, N))
-    hlo = _iterate_jit.lower(strategy, backend, state0,
+    hlo = _iterate_jit.lower(strategy, backend, _state0(strategy, d, one_chip),
                              200).compile().as_text()
     _assert_slab_built_once(hlo, N, d)
+
+
+def test_collectives_reads_tuples():
+    lines = ["  %a = (f32[10]{0}, f32[]{:T(128)}) all-reduce(f32[10]{0} %p, "
+             "f32[] %q), replica_groups={}, to_apply=%add",
+             "  %b = f32[4]{0} fusion(f32[4] %all-reduce.9), calls=%f",
+             "  %c = f32[8,85]{1,0} all-gather(f32[2,85]{1,0} %l), "
+             "dimensions={0}",
+             "  %d = f32[2] all-reduce-start(f32[2] %p), to_apply=%add"]
+    assert collectives(lines) == [("all-reduce", 2), ("all-gather", 1),
+                                  ("all-reduce-start", 1)]
+
+
+# WADI's clients on the 2x2 host: 20 clients of 151,200 rows, 5 per chip
+WADI_C, WADI_N, WADI_D = 20, 151200, 84
+
+
+def _wadi_shards(four_chips):
+    rows = NamedSharding(four_chips, P("data"))
+    return (jax.ShapeDtypeStruct((WADI_C, WADI_N, WADI_D), F32,
+                                 sharding=rows),
+            jax.ShapeDtypeStruct((WADI_C, WADI_N), F32, sharding=rows))
+
+
+def _assert_per_chip(hlo: str):
+    """Each chip holds its own 5 clients' rows; no program gathers the
+    20 clients' rows onto one chip."""
+    per = WADI_C // 4
+    assert f"f32[{per},{WADI_N},{WADI_D}]" in hlo
+    assert f"f32[{WADI_C},{WADI_N}" not in hlo
+
+
+def test_sharded_init_compiles(four_chips, on_tpu):
+    """DEM's fed-kmeans init on sharded clients, at WADI's per-chip
+    shapes: the local k-means and its kernel run inside ``shard_map``
+    (outside it the TPU compiler refuses to partition a Mosaic kernel),
+    each Lloyd loop reads a slab built before it, one ``all_gather`` of
+    the local centers and sizes is the init's only collective, and the
+    data moments take one ``psum``."""
+    data, mask = _wadi_shards(four_chips)
+    replicated = NamedSharding(four_chips, P())
+    key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                               sharding=replicated)
+    hlo = federated_kmeans_sharded.lower(
+        key, data, mask, mesh=four_chips, k_global=K).compile().as_text()
+    _assert_slab_built_once(hlo, WADI_N, WADI_D)
+    _assert_per_chip(hlo)
+    assert collectives(hlo.splitlines()) == [("all-gather", 1)]
+    centers = jax.ShapeDtypeStruct((K, WADI_D), F32, sharding=replicated)
+    hlo = init_from_means_sharded.lower(
+        centers, data, mask, mesh=four_chips).compile().as_text()
+    _assert_per_chip(hlo)
+    assert [op for op, _ in collectives(hlo.splitlines())] == ["all-reduce"]
+
+
+def test_sharded_round_loop_compiles(four_chips, on_tpu):
+    """DEM's round loop over sharded clients, at WADI's per-chip shapes:
+    each shard's slab is prepared once, before the loop, and a round's
+    only collective is one tuple all-reduce of the statistics s0, s1, s2
+    and the log-likelihood. (The weight sum, the same in every round,
+    XLA reduces once, before the loop.)"""
+    strategy = DEMStrategy(k=K)
+    backend = ShardedClients(*_wadi_shards(four_chips), four_chips)
+    hlo = _iterate_jit.lower(
+        strategy, backend,
+        _state0(strategy, WADI_D, NamedSharding(four_chips, P())),
+        200).compile().as_text()
+    _assert_slab_built_once(hlo, WADI_N, WADI_D)
+    _assert_per_chip(hlo)
+    assert collectives(loop_lines(hlo)) == [("all-reduce", 4)]
