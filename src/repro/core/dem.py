@@ -38,7 +38,7 @@ from repro.core.config import (FitConfig, is_source_list, resolve_backend,
                                resolve_estep_backend)
 from repro.core.em import (computed_lanes, e_step_stats, fit_gmm,
                            init_from_means, init_from_means_sharded, m_step,
-                           prepare_rows, prepared_bytes)
+                           prepare_rows, prepared_bytes, slab_row_block)
 from repro.core.gmm import GMM
 from repro.core.kmeans import (federated_kmeans, federated_kmeans_sharded,
                                gathered_floats)
@@ -291,6 +291,11 @@ class DEMStrategy:
         fused kernel's slab, padded once before the round loop, or the
         rows themselves (``repro.core.em.prepare_rows``)."""
         return prepare_rows(x, w, self._estep(), self.chunk)
+
+    def row_block(self):
+        """Rows of the blocks a client's E-step computes up to its last
+        weighted row, where its slab lets it skip the rest; else None."""
+        return slab_row_block(self._estep(), self.chunk)
 
     def prepared_bytes(self, backend, phase: str):
         """Device bytes of the clients' rows padded once for the kernels:

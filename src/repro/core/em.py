@@ -379,6 +379,17 @@ def prepared_bytes(n: int, d: int, backend: str, chunk_size: Optional[int],
     return ops.slab_bytes(n, d, weights)
 
 
+def slab_row_block(backend: str, chunk_size: Optional[int]) -> Optional[int]:
+    """Rows of the blocks the fused E-step computes over the slab
+    :func:`prepare_rows` builds: it computes a client's blocks up to its
+    last weighted row and skips the rest (``repro.kernels.ops.Slab``).
+    None where no slab is built."""
+    if backend != "fused" or chunk_size is not None:
+        return None
+    from repro.kernels import ops  # local import: kernels are optional
+    return ops.BLOCK_N
+
+
 def computed_lanes(d: int, backend: str) -> int:
     """Feature width an engine op computes over on a resolved ``backend``:
     the fused kernels pad the ``d`` features to the lane width

@@ -29,7 +29,7 @@ from jax.profiler import TraceAnnotation
 
 from repro.core.config import FitConfig, is_source_list
 from repro.core.em import (EMResult, computed_lanes, fit_gmm_bic_cfg,
-                           fit_gmm_cfg, fit_prepared_bytes)
+                           fit_gmm_cfg, fit_prepared_bytes, slab_row_block)
 from repro.core.gmm import GMM, merge_gmms
 from repro.core.partition import ClientSplit
 from repro.data.sources import DataSource, SyntheticGMMSource
@@ -302,7 +302,10 @@ class FedGenStrategy:
                                       self.config)
             counters = slab_counters(
                 backend, lanes, prepared_bytes=None if each is None
-                else backend.num_clients * each)
+                else backend.num_clients * each,
+                row_block=slab_row_block(
+                    self.config.resolved_estep(),
+                    self.config.resolve_chunk(source=False)))
             with TraceAnnotation("repro.fedgen.local", **counters):
                 stacked, lls, iters = train_locals_cfg(
                     state["k_local"], backend.data, backend.mask,

@@ -87,6 +87,10 @@ class FederationStrategy(Protocol):
     - ``lanes_computed(d) -> int`` (optional) — the feature width
       ``local_step`` computes over (d, or d padded by a kernel), for the
       round loop's profiler counters; left out of them when absent.
+    - ``row_block() -> int | None`` (optional) — the rows of the blocks
+      ``local_step``'s kernel computes up to a client's last weighted
+      row, skipping the rest of its padded rows, for the round loop's
+      ``rows_computed`` counter; absent or None, every padded row counts.
     - ``prepare_client(x, w)`` (optional) — one resident client's rows as
       ``local_step`` reads them, built once before the jitted round loop
       (the fused kernel's padded slab, or ``x`` itself); absent, or on
@@ -454,11 +458,15 @@ class ShardedClients:
 
 def slab_counters(backend, lanes_computed: Optional[int] = None,
                   cohort_size: Optional[int] = None,
-                  prepared_bytes: Optional[int] = None) -> dict:
+                  prepared_bytes: Optional[int] = None,
+                  row_block: Optional[int] = None) -> dict:
     """Counters of the client data one round computes over, for a
     profiler span: ``clients`` (the cohort's size when one is sampled);
     ``rows``, their real rows; ``rows_computed``, the rows of the padded
-    ``(clients, N, d)`` slab; ``lanes``, the feature width d;
+    ``(clients, N, d)`` slab the clients' op computes over: every row, or
+    where the op skips each client's blocks of ``row_block`` rows past its
+    last real row, as the layer that picks its backend gives it, each
+    client's rows rounded up to a block; ``lanes``, the feature width d;
     ``lanes_computed``, the width the clients' op computes over, as the
     layer that picks its backend gives it (left out where none does);
     ``prepared_bytes``, the device bytes of padded kernel operands built
@@ -470,7 +478,8 @@ def slab_counters(backend, lanes_computed: Optional[int] = None,
     never fetched (that would wait for the device). So are a sampled
     cohort's rows, which change from round to round, and
     ``rows_computed`` for source clients, whose block padding the engine
-    owns."""
+    owns. A sampled cohort, whose sizes are not known on the host, counts
+    every padded row."""
     d = int(backend.dim)
     c = int(backend.num_clients if cohort_size is None else cohort_size)
     out = {"clients": c, "lanes": d}
@@ -478,15 +487,21 @@ def slab_counters(backend, lanes_computed: Optional[int] = None,
         out["lanes_computed"] = int(lanes_computed)
     if prepared_bytes is not None:
         out["prepared_bytes"] = int(prepared_bytes)
-    if backend.kind != "sources":
-        out["rows_computed"] = c * int(backend.data.shape[1])
-    if backend.kind == "sharded":
-        out.update(shard_counters(backend))
+    sizes = None
     if cohort_size is None:
         sizes = backend.sizes if backend.kind == "sources" \
             else getattr(getattr(backend, "split", None), "sizes", None)
         if isinstance(sizes, (np.ndarray, list, tuple)):
             out["rows"] = int(np.sum(sizes))
+        else:
+            sizes = None
+    if backend.kind != "sources":
+        out["rows_computed"] = c * int(backend.data.shape[1])
+        if row_block is not None and sizes is not None:
+            blocks = -(-np.asarray(sizes, np.int64) // row_block)
+            out["rows_computed"] = int(np.sum(blocks)) * row_block
+    if backend.kind == "sharded":
+        out.update(shard_counters(backend))
     return out
 
 
@@ -761,9 +776,10 @@ def run_rounds(strategy, clients, *, key: Optional[jax.Array] = None,
     # the loop spans' counters; the strategy gives the width the clients
     # compute over
     lanes = getattr(strategy, "lanes_computed", None)
+    block = getattr(strategy, "row_block", None)
     slab = None if one_shot else slab_counters(
         backend, None if lanes is None else lanes(int(backend.dim)),
-        cohort_size, prepared_in("loop"))
+        cohort_size, prepared_in("loop"), None if block is None else block())
     if backend.kind == "sharded" and not one_shot:
         # one round's psum carries one client's payload, summed
         per_round = strategy.round_payload(ledger_backend, state0)

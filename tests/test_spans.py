@@ -26,7 +26,7 @@ from repro.core.kmeans import SEED_ROWS
 from repro.fed.runtime import (ShardedClients, _iterate_jit, make_backend,
                                slab_counters)
 from repro.fed.strategies import FedKMeansStrategy
-from repro.kernels.ops import LANES, padded_lanes, slab_bytes
+from repro.kernels.ops import BLOCK_N, LANES, padded_lanes, slab_bytes
 from repro.serve import ScoreConfig, ScoreRequest, ScoringEngine
 from conftest import planted_gmm_data
 
@@ -151,6 +151,12 @@ def _slab(split):
             "lanes_computed": D}
 
 
+def _ragged_rows(split):
+    """The rows the fused E-step computes: each client's rows rounded up
+    to the kernel's row block, the blocks past them skipped."""
+    return int(np.sum(-(-np.asarray(split.sizes) // BLOCK_N))) * BLOCK_N
+
+
 def test_lanes_computed_is_the_lane_multiple():
     assert LANES == 128
     assert [padded_lanes(d) for d in (1, 38, 84, 128, 129)] == \
@@ -202,6 +208,40 @@ def test_prepared_bytes_count_the_client_slab(tmp_path, split, backend):
     # the fed-kmeans init assigns on "auto", the reference path on a CPU
     assert all("prepared_bytes" not in c
                for c in counters(spans, "repro.rounds.init"))
+
+
+@pytest.fixture(scope="module")
+def wide_split():
+    """Clients of several kernel row blocks each, of unequal sizes."""
+    x, y, _ = planted_gmm_data(np.random.default_rng(5), n=3000, d=D, k=K,
+                               spread=5.0)
+    return partition(np.random.default_rng(6), x, y, CLIENTS, "dirichlet",
+                     1.0)
+
+
+@pytest.mark.parametrize("backend", ["fused", "reference"])
+def test_rows_computed_skip_each_clients_padded_blocks(tmp_path, wide_split,
+                                                       backend):
+    """The fused E-step computes each client's row blocks up to its last
+    row: the local fits' span and the round loop's count Σ ⌈n_c/512⌉·512
+    rows. The reference path computes the whole padded slab."""
+    split = wide_split
+    n = split.data.shape[1]
+    assert _ragged_rows(split) < CLIENTS * n
+    config = FitConfig(max_iter=2, backend=backend)
+    fed = FedGenGMM(k_clients=K, k_global=K, h=20, config=config)
+    dem = DEM(K, config=config)
+    _, spans = traced(tmp_path, lambda: (
+        fed.run(split, key=jax.random.key(0)),
+        dem.run(split, key=jax.random.key(0))))
+    want = _ragged_rows(split) if backend == "fused" else CLIENTS * n
+    for name in ("repro.fedgen.local", "repro.rounds.loop"):
+        assert [c["rows_computed"] for c in counters(spans, name)] == \
+            [want], name
+    # a sampled cohort's sizes are not known on the host: every padded row
+    backend = make_backend(split)
+    assert slab_counters(backend, LANES, cohort_size=2,
+                         row_block=BLOCK_N)["rows_computed"] == 2 * n
 
 
 def test_prepared_bytes_on_the_chip(monkeypatch, split):
@@ -260,7 +300,8 @@ def test_sharded_dem_spans_count_the_mesh(tmp_path, split):
     assert counters(spans, "repro.rounds.init") == [dict(
         mesh_counters, allgather_bytes=CLIENTS * (K * D + K) * 4)]
     assert counters(spans, "repro.rounds.loop") == [dict(
-        _slab(split), lanes_computed=LANES, **mesh_counters,
+        _slab(split), rows_computed=_ragged_rows(split),
+        lanes_computed=LANES, **mesh_counters,
         prepared_bytes=CLIENTS * slab_bytes(split.data.shape[1], D),
         allreduce_bytes=(K + 2 * K * D + 2) * 4)]
     assert counters(spans, "repro.rounds.finalize") == [

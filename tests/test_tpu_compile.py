@@ -8,7 +8,9 @@ the compiler refuses) at no chip time. Each compiled program must hold the
 Mosaic kernel itself (``tpu_custom_call``), not an interpret-mode loop.
 
 The fit loops are compiled whole as well: their kernel operands must be
-padded once, before ``lax.while_loop``, never inside a loop body.
+padded once, before ``lax.while_loop``, never inside a loop body, and a
+loop's E-step over many clients is one kernel call over all of them,
+never a loop that slices one client's slab at a time.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -133,7 +135,7 @@ _CALLS = re.compile(r"(?:body|condition|calls|to_apply|true_computation|"
                     r"false_computation)=%([\w.\-]+)"
                     r"|branch_computations=\{([^}]*)\}")
 _PAD_OR_COPY = re.compile(r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* "
-                          r"(pad|copy)\(")
+                          r"(pad|copy|dynamic-slice)\(")
 
 
 def loop_lines(hlo: str) -> list:
@@ -165,8 +167,9 @@ def loop_lines(hlo: str) -> list:
 
 
 def loop_pads(hlo: str) -> list:
-    """(op, shape) of every ``pad`` and ``copy`` in a ``while`` body of the
-    compiled program, or in a computation such a body calls."""
+    """(op, shape) of every ``pad``, ``copy`` and ``dynamic-slice`` in a
+    ``while`` body of the compiled program, or in a computation such a
+    body calls."""
     found = []
     for line in loop_lines(hlo):
         m = _PAD_OR_COPY.match(line)
@@ -194,9 +197,9 @@ def collectives(lines) -> list:
 
 
 def _assert_slab_built_once(hlo: str, n: int, d: int):
-    """The kernel runs, and no loop rebuilds the client slab: neither the
-    padded rows ``(n_pad, 128·)`` nor the ``(n, 1)`` weight column, before
-    or after its padding."""
+    """The kernel runs, and no loop rebuilds or slices the client slab:
+    neither the padded rows ``(n_pad, 128·)`` nor the ``(n, 1)`` weight
+    column, before or after its padding."""
     assert "tpu_custom_call" in hlo
     n_pad = -(-n // ops.BLOCK_N) * ops.BLOCK_N
     slab = {(n_pad, ops.padded_lanes(d)), (n, 1), (n_pad, 1)}
@@ -205,10 +208,22 @@ def _assert_slab_built_once(hlo: str, n: int, d: int):
     assert in_loops == [], in_loops
 
 
+def _assert_one_ragged_estep(hlo: str, clients: int):
+    """The loop bodies hold one Mosaic E-step call, over all ``clients``
+    at once, whose first operand is their ``(clients,)`` int32 count of
+    row blocks to compute."""
+    calls = [line for line in loop_lines(hlo)
+             if re.match(r"\s*%estep_stats_pallas[.\d]* = ", line)]
+    assert len(calls) == 1, calls
+    assert 'custom_call_target="tpu_custom_call"' in calls[0]
+    assert f"operand_layout_constraints={{s32[{clients}]{{0}}, " in calls[0]
+
+
 def test_loop_pads_reads_while_bodies():
     hlo = """%body (p: f32[4]) -> f32[4] {
   %a = f32[8,128]{1,0} pad(%p, %z), padding=0_4x0_0
   %b = f32[4]{0} fusion(%p), kind=kLoop, calls=%fused
+  %s = f32[1,512,128]{2,1,0} dynamic-slice(%p, %i)
 }
 
 %fused (q: f32[4]) -> f32[4] {
@@ -220,7 +235,9 @@ ENTRY %main (x: f32[4]) -> f32[4] {
   ROOT %w = f32[4] while(%x), condition=%cond, body=%body
 }
 """
-    assert sorted(loop_pads(hlo)) == [("copy", (512, 1)), ("pad", (8, 128))]
+    assert sorted(loop_pads(hlo)) == [("copy", (512, 1)),
+                                      ("dynamic-slice", (1, 512, 128)),
+                                      ("pad", (8, 128))]
 
 
 def test_fedgen_local_fits_pad_once(one_chip, on_tpu):
@@ -235,6 +252,22 @@ def test_fedgen_local_fits_pad_once(one_chip, on_tpu):
         key, _spec(one_chip, C, N, d), _spec(one_chip, C, N), k=K,
         config=config).compile().as_text()
     _assert_slab_built_once(hlo, N, d)
+
+
+def test_wadi_local_fits_run_one_ragged_kernel(one_chip, on_tpu):
+    """FedGenGMM's vmapped local fits at WADI's shapes (20 clients, a
+    151,552 x 128 slab each): the EM loop's E-step is one kernel over the
+    20 clients with their block counts, on slabs built before the loop."""
+    key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                               sharding=one_chip)
+    config = FitConfig(backend="fused").resolved_for("em").replace(
+        seed=0, init="auto")
+    hlo = _train_locals_jit.lower(
+        key, _spec(one_chip, WADI_C, WADI_N, WADI_D),
+        _spec(one_chip, WADI_C, WADI_N), k=K,
+        config=config).compile().as_text()
+    _assert_slab_built_once(hlo, WADI_N, WADI_D)
+    _assert_one_ragged_estep(hlo, WADI_C)
 
 
 def _state0(strategy, d, sharding):
@@ -255,6 +288,19 @@ def test_dem_round_loop_pads_once(one_chip, on_tpu):
     hlo = _iterate_jit.lower(strategy, backend, _state0(strategy, d, one_chip),
                              200).compile().as_text()
     _assert_slab_built_once(hlo, N, d)
+
+
+def test_wadi_dem_round_loop_runs_one_ragged_kernel(one_chip, on_tpu):
+    """DEM's round loop over WADI's 20 resident clients: a round's E-step
+    is one kernel over every client with their block counts."""
+    strategy = DEMStrategy(k=K)
+    backend = SplitClients(_spec(one_chip, WADI_C, WADI_N, WADI_D),
+                           _spec(one_chip, WADI_C, WADI_N))
+    hlo = _iterate_jit.lower(
+        strategy, backend, _state0(strategy, WADI_D, one_chip),
+        200).compile().as_text()
+    _assert_slab_built_once(hlo, WADI_N, WADI_D)
+    _assert_one_ragged_estep(hlo, WADI_C)
 
 
 def test_collectives_reads_tuples():
@@ -323,5 +369,6 @@ def test_sharded_round_loop_compiles(four_chips, on_tpu):
         _state0(strategy, WADI_D, NamedSharding(four_chips, P())),
         200).compile().as_text()
     _assert_slab_built_once(hlo, WADI_N, WADI_D)
+    _assert_one_ragged_estep(hlo, WADI_C // 4)
     _assert_per_chip(hlo)
     assert collectives(loop_lines(hlo)) == [("all-reduce", 4)]
